@@ -1,8 +1,11 @@
 """Roofline extraction: dry-run artifacts → three-term analysis per cell.
 
-    compute    = HLO_FLOPs / (chips × 197 TFLOP/s)
-    memory     = HLO_bytes / (chips × 819 GB/s)
-    collective = Σ collective-bytes / (chips × 50 GB/s per link)
+    compute    = HLO_FLOPs / (chips × peak bf16 FLOP/s)
+    memory     = HLO_bytes / (chips × peak HBM bytes/s)
+    collective = Σ collective-bytes / (chips × ICI bytes/s per link)
+
+with the peaks of the dry-run's target chip (``TARGET_DEVICE_KIND``) read
+from ``repro.peaks``.
 
 HLO_FLOPs / bytes come from compiled cost_analysis with the scan-depth
 extrapolation (see launch/dryrun.py). Under SPMD the compiled module IS one
@@ -23,9 +26,10 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # bytes/s / chip
-ICI_BW = 50e9             # bytes/s / link
+from repro.peaks import chip_peaks
+
+# the dry-run models a v5e pod on placeholder devices
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def load_cells(dryrun_dir: str, mesh: str = "single") -> list[dict]:
@@ -42,9 +46,10 @@ def analyze(rec: dict) -> dict:
     flops = max(c["flops"], 0.0)          # per-device (SPMD module)
     byts = max(c["bytes_accessed"], 0.0)  # per-device
     coll = sum(max(v, 0) for v in c["collective_bytes"].values())
-    t_compute = flops / PEAK_FLOPS
-    t_memory = byts / HBM_BW
-    t_coll = coll / ICI_BW
+    peaks = chip_peaks(TARGET_DEVICE_KIND)
+    t_compute = flops / peaks.bf16_flops
+    t_memory = byts / peaks.hbm_bw
+    t_coll = coll / peaks.ici_bw_per_link
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     # MODEL_FLOPS: 6·N·D for train (fwd+bwd), 2·N·D for single forward
@@ -139,10 +144,14 @@ def main(argv=None):
     if args.out_json:
         sys.path.insert(0, __file__.rsplit("/", 1)[0])
         from common import bench_doc, write_bench_json
+        peaks = chip_peaks(TARGET_DEVICE_KIND)
         doc = bench_doc(
             "roofline",
-            config={"dryrun_dir": args.dryrun_dir, "peak_flops": PEAK_FLOPS,
-                    "hbm_bw": HBM_BW, "ici_bw": ICI_BW},
+            config={"dryrun_dir": args.dryrun_dir,
+                    "device_kind": TARGET_DEVICE_KIND,
+                    "peak_flops": peaks.bf16_flops,
+                    "hbm_bw": peaks.hbm_bw,
+                    "ici_bw": peaks.ici_bw_per_link},
             rows=rows,
             summary={"picks": {k: f"{r['arch']}|{r['shape']}"
                                for k, r in picks.items()}})

@@ -11,17 +11,9 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.peaks import chip_peaks
 
-def _peak_bf16() -> float:
-    """One v5e core's bf16 peak. ``repro.kernels.common`` imports jax at
-    module scope; deferring (with the same constant as fallback) keeps this
-    driver usable on the scheduler-only toolchain."""
-    try:
-        from repro.kernels.common import PEAK_BF16
-        return PEAK_BF16
-    except ImportError:
-        return 197e12
-
+TPU_TARGET = "TPU v5 lite"
 
 ARCANE_CLOCK = 265e6
 PAPER = {
@@ -47,8 +39,8 @@ def run(quiet: bool = False):
     for name, (gops, area, note) in PAPER.items():
         rows.append({"system": name, "gops": gops, "area_mm2": area,
                      "gops_per_mm2": gops / area})
-    # TPU target: one v5e core, int8 ops ≈ 2x bf16 peak on the MXU
-    tpu_int8 = 2 * _peak_bf16() / 1e9
+    # TPU target: one v5e chip's published int8 peak on the MXU
+    tpu_int8 = chip_peaks(TPU_TARGET).int8_ops / 1e9
     rows.append({"system": "TPU v5e core (target, int8)", "gops": tpu_int8,
                  "area_mm2": float("nan"), "gops_per_mm2": float("nan")})
     if not quiet:

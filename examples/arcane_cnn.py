@@ -63,16 +63,10 @@ def main():
     ref = np.asarray(conv_layer_ref(x, f))[0]
     assert np.array_equal(R, ref), "simulator disagrees with jnp oracle"
 
-    # TPU-target Pallas kernel (interpret mode on CPU); jax versions without
-    # the Element-indexed BlockSpec API skip this leg (jnp oracle still holds)
-    pallas_ok = True
-    try:
-        from repro.kernels import conv_layer
-        pk = np.asarray(conv_layer(x, f, block_rows=16))[0]
-        assert np.array_equal(pk, ref), "pallas kernel disagrees with oracle"
-    except AttributeError as e:
-        pallas_ok = False
-        print(f"  (pallas cross-check skipped: {e})")
+    # TPU-target Pallas kernel (interpret mode on CPU)
+    from repro.kernels import conv_layer
+    pk = np.asarray(conv_layer(x, f, block_rows=16))[0]
+    assert np.array_equal(pk, ref), "pallas kernel disagrees with oracle"
 
     stats = cop.rt.stats
     print(f"conv layer {H}x{W} 3ch int32 on 8-lane ARCANE")
@@ -87,8 +81,7 @@ def main():
     scalar = 3 * cost.macs + 3 * cost.elementwise
     print(f"  modeled speedup vs scalar RV32IMC: "
           f"{scalar / stats.total_cycles:.1f}x")
-    print("  simulator == pallas kernel == jnp oracle ✓" if pallas_ok
-          else "  simulator == jnp oracle ✓")
+    print("  simulator == pallas kernel == jnp oracle ✓")
 
 
 if __name__ == "__main__":

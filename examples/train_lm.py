@@ -35,7 +35,7 @@ def main():
     common = ["--arch", "granite-moe-1b-a400m", "--smoke",
               "--batch", str(batch), "--seq", str(seq),
               "--ckpt-dir", args.ckpt_dir, "--ckpt-every", "20",
-              "--lr", "3e-3"]
+              "--lr", "3e-3", "--backend", "ref"]
 
     print(f"=== phase 1: train to step {crash_at}, then 'crash' ===")
     r1 = train_launcher.run(common + ["--steps", str(crash_at)])

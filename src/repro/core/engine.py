@@ -56,23 +56,25 @@ class TraceEntry:
 class ArcaneEngine:
     """Dispatch facade used by every model layer.
 
-    backend: "pallas"  — Pallas kernels (TPU; interpret-mode on CPU),
+    backend: "pallas"  — Pallas kernels (Mosaic on a TPU),
              "ref"     — blocked-jnp reference path (pjit-partitionable; used
-                         by the multi-pod dry-run),
-             "auto"    — pallas on TPU, ref elsewhere.
+                         by the multi-pod dry-run and as the oracle).
+    interpret: run the Pallas kernels in interpret mode. ``None`` decides
+               from JAX's backend (interpret off a TPU, for the CPU tests);
+               ``False`` lowers them for a TPU from any host, which is how
+               the compile tests target a described chip.
     """
 
-    def __init__(self, backend: str = "auto", *, attn_block_q: int = 256,
+    def __init__(self, backend: str, *, attn_block_q: int = 256,
                  attn_block_k: int = 256, gemm_block: tuple = (128, 128, 128),
-                 record: bool = False):
-        if backend == "auto":
-            backend = "pallas" if jax.default_backend() == "tpu" else "ref"
+                 interpret: Optional[bool] = None, record: bool = False):
         if backend not in ("pallas", "ref"):
             raise ValueError(f"unknown backend {backend!r}")
         self.backend = backend
         self.attn_block_q = attn_block_q
         self.attn_block_k = attn_block_k
         self.gemm_block = gemm_block
+        self.interpret = interpret
         self.record = record
         self.trace: list[TraceEntry] = []
         # attention backend name differs: blocked-jnp ref is "chunked"
@@ -113,7 +115,8 @@ class ArcaneEngine:
             bm, bn, bk = self.gemm_block
             out = kernels.gemm(x2, w, c2, alpha=alpha, beta=beta,
                                block_m=bm, block_n=bn, block_k=bk,
-                               out_dtype=out_dtype or x.dtype)
+                               out_dtype=out_dtype or x.dtype,
+                               interpret=self.interpret)
         return out.reshape(*lead, n)
 
     def leakyrelu(self, x: jax.Array, *, negative_slope: float = 0.01) -> jax.Array:
@@ -122,7 +125,8 @@ class ArcaneEngine:
             return jnp.where(x >= 0, x, negative_slope * x)
         shape = x.shape
         x2 = x.reshape(-1, shape[-1])
-        return kernels.leakyrelu(x2, negative_slope=negative_slope).reshape(shape)
+        return kernels.leakyrelu(x2, negative_slope=negative_slope,
+                                 interpret=self.interpret).reshape(shape)
 
     def maxpool(self, x: jax.Array, *, win: int = 2,
                 stride: Optional[int] = None) -> jax.Array:
@@ -130,7 +134,8 @@ class ArcaneEngine:
         if self.backend == "ref":
             from repro.kernels.maxpool.ref import maxpool_ref
             return maxpool_ref(x, win=win, stride=stride)
-        return kernels.maxpool(x, win=win, stride=stride)
+        return kernels.maxpool(x, win=win, stride=stride,
+                               interpret=self.interpret)
 
     def conv_layer(self, x: jax.Array, f: jax.Array, *,
                    negative_slope: float = 0.0) -> jax.Array:
@@ -138,9 +143,9 @@ class ArcaneEngine:
         nf, _, kh, kw = f.shape
         self._log(4, x.dtype, (x.shape, f.shape),
                   2 * nf * cch * (h - kh + 1) * (w - kw + 1) * kh * kw)
-        backend = "pallas" if self.backend == "pallas" else "ref"
         return kernels.conv_layer(x, f, negative_slope=negative_slope,
-                                  backend=backend)
+                                  backend=self.backend,
+                                  interpret=self.interpret)
 
     def attention(self, q, k, v, *, causal=True, window=None, softcap=None,
                   scale=None, kv_len=None) -> jax.Array:
@@ -150,30 +155,17 @@ class ArcaneEngine:
         return kernels.flash_attention(
             q, k, v, causal=causal, window=window, softcap=softcap,
             scale=scale, kv_len=kv_len, block_q=self.attn_block_q,
-            block_k=self.attn_block_k, backend=self._attn_backend)
+            block_k=self.attn_block_k, backend=self._attn_backend,
+            interpret=self.interpret)
 
     def decode_attention(self, q, k, v, lengths, *, softcap=None,
                          scale=None, window=None) -> jax.Array:
         b, hq, d = q.shape
         s = k.shape[2]
         self._log(6, q.dtype, (q.shape, k.shape), 4 * b * hq * s * d)
-        backend = "pallas" if self.backend == "pallas" else "ref"
         return kernels.decode_attention(q, k, v, lengths, softcap=softcap,
                                         scale=scale, window=window,
                                         block_k=self.attn_block_k,
-                                        backend=backend)
+                                        backend=self.backend,
+                                        interpret=self.interpret)
 
-
-_DEFAULT: Optional[ArcaneEngine] = None
-
-
-def default_engine() -> ArcaneEngine:
-    global _DEFAULT
-    if _DEFAULT is None:
-        _DEFAULT = ArcaneEngine()
-    return _DEFAULT
-
-
-def set_default_engine(engine: ArcaneEngine) -> None:
-    global _DEFAULT
-    _DEFAULT = engine

@@ -1,32 +1,20 @@
 """Shared helpers for the Pallas kernel suite.
 
-All kernels target TPU (pl.pallas_call + explicit BlockSpec VMEM tiling) and
-are validated on CPU in interpret mode. ``interpret_default()`` picks the mode
-from the runtime backend so the same code path runs in both worlds; the
-``REPRO_PALLAS_INTERPRET`` env var forces it either way.
+All kernels target TPU (pl.pallas_call + explicit BlockSpec VMEM tiling).
+``interpret_default()`` runs them in interpret mode when JAX's backend is not
+a TPU, which is how the CPU tests validate them; on a TPU they always lower
+to Mosaic. Per-chip peaks live in ``repro.peaks``.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
-# TPU v5e hardware geometry the BlockSpecs are designed against.
-MXU_DIM = 128          # systolic array is 128x128
-VPU_LANES = 128        # vector unit lane count (8 sublanes x 128 lanes)
-VMEM_BYTES = 128 * 2**20   # ~128 MiB of VMEM per core
-HBM_BW = 819e9         # bytes/s
-PEAK_BF16 = 197e12     # FLOP/s
-ICI_BW = 50e9          # bytes/s/link
-
 
 @functools.cache
 def interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 

@@ -9,7 +9,8 @@ single program.
 
 q: (B, Hkv, G, D)  — G = Hq / Hkv query heads per KV head,
 k, v: (B, Hkv, S, D) — the cache, padded to the page multiple,
-lengths: (B, 1) int32 — valid cache length per sequence (ragged batch).
+lengths: (B,) int32 — valid cache length per sequence (ragged batch), held
+in SMEM through scalar prefetch and read as a scalar per program.
 
 Grid: (B, Hkv, pages); per-page blocks are skipped entirely once past the
 sequence length (`pl.when`), so short sequences in a ragged batch cost only
@@ -28,11 +29,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.common import NEG_INF, interpret_default, round_up
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, acc_ref, m_ref, l_ref,
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
                    *, nkv: int, bk: int, scale: float,
                    softcap: Optional[float], window: Optional[int]):
     ik = pl.program_id(2)
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
     start = jnp.maximum(length - window, 0) if window is not None else 0
 
     @pl.when(ik == 0)
@@ -93,27 +94,31 @@ def decode_attention_pallas(
         k = jnp.pad(k, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
     nkv = sp // bk
-    lengths2d = lengths.reshape(b, 1).astype(jnp.int32)
 
     kernel = functools.partial(_decode_kernel, nkv=nkv, bk=bk, scale=scale,
                                softcap=softcap, window=window)
-    return pl.pallas_call(
-        kernel,
+    # index maps take the prefetched lengths as a trailing (unused) argument
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, hkv, nkv),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, h, ik: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bb, h, ik: (bb, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bb, h, ik: (bb, h, ik, 0)),
-            pl.BlockSpec((1, 1), lambda bb, h, ik: (bb, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda bb, h, ik, ln: (bb, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda bb, h, ik, ln: (bb, h, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda bb, h, ik, ln: (bb, h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bb, h, ik: (bb, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, d),
+                               lambda bb, h, ik, ln: (bb, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, d), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, lengths2d)
+    )(lengths.reshape(b).astype(jnp.int32), q, k, v)

@@ -105,7 +105,7 @@ def extrapolate(full: dict, p1: dict, p2: dict, n_periods: int) -> dict:
     }
 
 
-def lower_cell(arch: str, shape_name: str, mesh, *, backend: str = "ref",
+def lower_cell(arch: str, shape_name: str, mesh, *,
                n_periods: int | None = None, constrain_acts: bool = False,
                cfg_overrides: dict | None = None):
     """Lower+compile one cell; returns the result record.
@@ -123,7 +123,9 @@ def lower_cell(arch: str, shape_name: str, mesh, *, backend: str = "ref",
             repl["n_enc_layers"] = n_periods
         cfg = _dc.replace(cfg, **repl)
     shape = SHAPES[shape_name]
-    engine = ArcaneEngine(backend=backend)
+    # XLA cannot partition a Mosaic kernel over a mesh, so the sharded
+    # dry-run lowers the partitionable blocked-jnp path
+    engine = ArcaneEngine(backend="ref")
     model = LM(cfg, engine, unroll=n_periods is not None)
     from repro.distributed.sharding import set_activation_mesh
     set_activation_mesh(mesh if constrain_acts else None)
@@ -211,8 +213,6 @@ def main() -> None:
                     default="single")
     ap.add_argument("--all", action="store_true",
                     help="run every applicable (arch x shape) cell")
-    ap.add_argument("--backend", default="ref",
-                    help="engine backend for lowering (ref|pallas)")
     ap.add_argument("--no-roofline", action="store_true",
                     help="skip the 1/2-period extrapolation compiles")
     ap.add_argument("--constrain-acts", action="store_true",
@@ -250,18 +250,17 @@ def main() -> None:
                 ov = ({"ring_local_cache": True}
                       if args.ring_local_cache else None)
                 rec = lower_cell(arch, shape_name, mesh,
-                                 backend=args.backend,
                                  constrain_acts=args.constrain_acts,
                                  cfg_overrides=ov)
                 if mesh_name == "single" and not args.no_roofline:
                     # depth extrapolation: correct once-per-scan counting
                     cfgK = get_config(arch)
                     p1 = lower_cell(arch, shape_name, mesh,
-                                    backend=args.backend, n_periods=1,
+                                    n_periods=1,
                                     constrain_acts=args.constrain_acts,
                                     cfg_overrides=ov)
                     p2 = lower_cell(arch, shape_name, mesh,
-                                    backend=args.backend, n_periods=2,
+                                    n_periods=2,
                                     constrain_acts=args.constrain_acts,
                                     cfg_overrides=ov)
                     rec["corrected"] = extrapolate(rec, p1, p2,

@@ -1,9 +1,12 @@
-"""Serving launcher: batched continuous decoding on the host mesh.
+"""Serving launcher: batched continuous decoding on one device.
 
-Usage::
+Runs the Pallas kernels on a TPU by default; anywhere else it fails unless
+``--backend ref`` is given (see ``repro.launch.chip``). Usage::
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-32b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch stablelm-3b \
         --requests 8 --max-new 16
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-32b --smoke \
+        --backend ref      # CPU
 """
 from __future__ import annotations
 
@@ -15,6 +18,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.engine import ArcaneEngine
+from repro.launch.chip import BACKENDS, check_backend, use_compile_cache
 from repro.models.transformer import LM
 from repro.serving.engine import ServeSession
 
@@ -27,12 +31,15 @@ def run(argv=None) -> dict:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=256)
-    ap.add_argument("--backend", default="ref")
+    ap.add_argument("--backend", choices=BACKENDS, default="pallas")
     args = ap.parse_args(argv)
+    check_backend(args.backend)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = LM(cfg, ArcaneEngine(backend=args.backend))
-    params = model.init_params(jax.random.key(0))
+    # one compiled init program instead of an eager op per weight
+    params = jax.jit(model.init_params)(jax.random.key(0))
     sess = ServeSession(model, params, max_slots=args.slots,
                         max_len=args.max_len)
     rng = np.random.default_rng(0)

@@ -12,10 +12,16 @@ Fault-tolerance machinery (single-host shapes of the multi-pod mechanisms):
     step number (at pod scale this feeds the reschedule/elastic controller;
     here it is surfaced in metrics and the log).
 
+Backend: training runs the blocked-jnp ``ref`` path, asked for explicitly
+with ``--backend ref``. The default ``pallas`` is refused, for two reasons
+found by compiling the step for a v5e:2x2 topology: the Pallas kernels define
+no backward pass (``pallas_call`` has no JVP for them), and XLA cannot
+partition a Mosaic kernel inside a program sharded over several devices.
+
 Usage::
 
     PYTHONPATH=src python -m repro.launch.train --arch granite-moe-1b-a400m \
-        --smoke --steps 50 --batch 8 --seq 128
+        --smoke --steps 50 --batch 8 --seq 128 --backend ref
 """
 from __future__ import annotations
 
@@ -34,10 +40,17 @@ from repro.core.engine import ArcaneEngine
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
 from repro.distributed.sharding import (batch_pspecs, param_pspecs,
                                         to_shardings, zero_pspecs)
+from repro.launch.chip import BACKENDS, use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.transformer import LM
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.train.step import make_train_step
+
+
+PALLAS_TRAIN_REFUSAL = (
+    "training cannot run --backend pallas: the Pallas kernels define no "
+    "backward pass, and XLA cannot partition a Mosaic kernel over a mesh; "
+    "pass --backend ref")
 
 
 class Preemption:
@@ -67,9 +80,12 @@ def run(argv=None) -> dict:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--watchdog-factor", type=float, default=5.0)
-    ap.add_argument("--backend", default="ref")
+    ap.add_argument("--backend", choices=BACKENDS, default="pallas")
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args(argv)
+    if args.backend == "pallas":
+        raise SystemExit(PALLAS_TRAIN_REFUSAL)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     engine = ArcaneEngine(backend=args.backend)
@@ -84,12 +100,17 @@ def run(argv=None) -> dict:
     source = SyntheticLM(data_cfg)
 
     with mesh:
-        params = model.init_params(jax.random.key(0))
-        opt_state = adamw_init(opt_cfg, params)
-        p_sh = to_shardings(param_pspecs(params, mesh), mesh)
-        o_sh = to_shardings(zero_pspecs(opt_state, mesh), mesh)
-        params = jax.device_put(params, p_sh)
-        opt_state = jax.device_put(opt_state, o_sh)
+        # jitted inits write straight into their shardings: no device ever
+        # holds the whole model or its optimizer state
+        p_shapes = model.param_shapes()
+        p_sh = to_shardings(param_pspecs(p_shapes, mesh), mesh)
+        o_sh = to_shardings(zero_pspecs(
+            jax.eval_shape(lambda p: adamw_init(opt_cfg, p), p_shapes),
+            mesh), mesh)
+        params = jax.jit(model.init_params, out_shardings=p_sh)(
+            jax.random.key(0))
+        opt_state = jax.jit(lambda p: adamw_init(opt_cfg, p),
+                            out_shardings=o_sh)(params)
         step_fn = jax.jit(
             make_train_step(model, opt_cfg, microbatches=args.microbatches),
             in_shardings=(p_sh, o_sh, None),

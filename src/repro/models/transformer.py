@@ -8,13 +8,13 @@ assigned configs — and the activation-checkpoint policy applies per period.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.engine import ArcaneEngine, default_engine
+from repro.core.engine import ArcaneEngine
 from repro.models import blocks as blk
 from repro.models.layers import (embed, embedding_init, make_norm,
                                  sinusoidal_positions, unembed)
@@ -23,10 +23,11 @@ PyTree = Any
 
 
 def _stack_init(key, n: int, init_fn):
-    """Initialise ``n`` copies of a block and stack leaves on axis 0."""
-    keys = jax.random.split(key, n)
-    trees = [init_fn(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+    """Initialise ``n`` copies of a block, stacked on a leading axis.
+
+    One vmapped init writes the stack directly: no per-layer copies to
+    concatenate, and one traced block instead of ``n``."""
+    return jax.vmap(init_fn)(jax.random.split(key, n))
 
 
 def _index_tree(tree: PyTree, i):
@@ -36,10 +37,10 @@ def _index_tree(tree: PyTree, i):
 class LM:
     """Decoder-only (optionally enc-dec / vision-prefixed) language model."""
 
-    def __init__(self, cfg: ModelConfig, engine: Optional[ArcaneEngine] = None,
+    def __init__(self, cfg: ModelConfig, engine: ArcaneEngine,
                  *, remat: bool = True, unroll: bool = False):
         self.cfg = cfg
-        self.engine = engine or default_engine()
+        self.engine = engine
         self.remat = remat
         # unroll=True replaces the period scan with a Python loop — used by
         # the dry-run's depth-extrapolation compiles (cost_analysis counts a
@@ -178,11 +179,12 @@ class LM:
         dtype = dtype or cfg.cdtype
 
         def one(spec):
-            def mk(i):
-                return blk.init_block_cache(cfg, spec, batch, max_len, dtype,
-                                            cross_len=enc_len)
-            return jax.tree.map(lambda *xs: jnp.stack(xs),
-                                *[mk(i) for i in range(cfg.n_periods)])
+            # caches start at zero: allocate the (n_periods, ...) stack once
+            shapes = jax.eval_shape(lambda: blk.init_block_cache(
+                cfg, spec, batch, max_len, dtype, cross_len=enc_len))
+            return jax.tree.map(
+                lambda x: jnp.zeros((cfg.n_periods, *x.shape), x.dtype),
+                shapes)
 
         return tuple(one(spec) for spec in cfg.pattern)
 

@@ -58,16 +58,19 @@ class ServeSession:
         self.last_tokens = np.zeros((max_slots,), np.int32)
         self._uid = 0
         self._key = jax.random.key(seed)
-        self._prefill1 = jax.jit(
-            lambda p, b, c: model.prefill(p, b, c))
-        self._decode = jax.jit(
-            lambda p, t, po, c: model.decode_step(p, t, po, c))
+        self._prefill1 = jax.jit(model.prefill)
+        self._decode = jax.jit(model.decode_step)
         self.pending: list[Request] = []
         self.finished: list[Request] = []
 
     # ------------------------------------------------------------------ API
     def submit(self, prompt, **kw) -> Request:
         req = Request(uid=self._uid, prompt=np.asarray(prompt, np.int32), **kw)
+        s = len(req.prompt)
+        if s == 0 or s + req.max_new_tokens > self.max_len:
+            raise ValueError(
+                f"prompt of {s} tokens + {req.max_new_tokens} new tokens "
+                f"does not fit max_len={self.max_len}")
         self._uid += 1
         self.pending.append(req)
         return req
@@ -78,7 +81,6 @@ class ServeSession:
                 continue
             req = self.pending.pop(0)
             s = len(req.prompt)
-            assert s + req.max_new_tokens <= self.max_len, "prompt too long"
             one_cache = self.model.init_cache(1, self.max_len)
             logits, one_cache = self._prefill1(
                 self.params, {"tokens": jnp.asarray(req.prompt[None])},

@@ -5,18 +5,6 @@ import subprocess
 import sys
 import textwrap
 
-import pytest
-
-# The sharding scenarios use explicit-mode meshes (`jax.sharding.AxisType`,
-# jax >= 0.5); on older installs every subprocess dies with the same
-# AttributeError, so probe the capability once and skip the module cleanly.
-# (Importing jax here is safe — device counts are locked per subprocess.)
-jax = pytest.importorskip("jax")
-if not hasattr(jax.sharding, "AxisType"):
-    pytest.skip("installed jax lacks jax.sharding.AxisType "
-                "(explicit-mode mesh API, jax>=0.5)",
-                allow_module_level=True)
-
 ENV = {**os.environ, "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
        "PYTHONPATH": "src"}
 

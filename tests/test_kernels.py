@@ -3,16 +3,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# The kernel wrappers target the renamed pallas TPU compiler-params API
-# (jax >= 0.5, `pltpu.CompilerParams`); on older installs every test would
-# fail inside pallas_call, so skip the module with a capability probe
-# rather than a brittle version string compare.
-pltpu = pytest.importorskip("jax.experimental.pallas.tpu")
-if not hasattr(pltpu, "CompilerParams"):
-    pytest.skip("installed jax's pallas.tpu lacks CompilerParams "
-                "(kernel suite needs the renamed jax>=0.5 API)",
-                allow_module_level=True)
-
 from repro.kernels import (conv_layer, decode_attention, flash_attention,
                            gemm, leakyrelu, maxpool)
 from repro.kernels.convlayer.ref import conv_layer_ref
