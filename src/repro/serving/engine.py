@@ -7,15 +7,34 @@ every step decodes one token for all live slots. Ragged lengths are free:
 the decode kernel skips cache pages past each slot's length, so a just-
 admitted short sequence does not pay for its neighbours (the kernel-level
 straggler mitigation described in the decode kernel docstring).
+
+Every phase of ``step()`` runs inside a ``jax.profiler.TraceAnnotation``
+span, so a profiler trace shows what the host was doing while the device
+waited. The spans cost under a microsecond each with the profiler
+off, and change nothing that is jitted::
+
+    serve.step                       one per step()
+    ├─ serve.admit  (uid, prompt_len, slot)   one per admission
+    │  ├─ serve.init_cache           the batch-1 cache
+    │  ├─ serve.prefill              dispatch of the jitted prefill
+    │  ├─ serve.insert               the copy into the batched cache
+    │  └─ serve.first_token          sampling the first token
+    ├─ serve.decode                  dispatch of the jitted decode step
+    ├─ serve.fetch                   logits to the host
+    └─ serve.sample                  per-slot sampling and bookkeeping
+
+The admission spans carry the request's ``uid``.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.transformer import LM
 
@@ -30,6 +49,10 @@ class Request:
     temperature: float = 0.0
     out_tokens: list = dataclasses.field(default_factory=list)
     done: bool = False
+    # time.perf_counter() when its admission started, and when its first
+    # token was on the host: the stall one admission puts on every live slot
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 def _insert_slot(batched: PyTree, one: PyTree, slot: int) -> PyTree:
@@ -81,16 +104,24 @@ class ServeSession:
                 continue
             req = self.pending.pop(0)
             s = len(req.prompt)
-            one_cache = self.model.init_cache(1, self.max_len)
-            logits, one_cache = self._prefill1(
-                self.params, {"tokens": jnp.asarray(req.prompt[None])},
-                one_cache)
-            self.cache = _insert_slot(self.cache, one_cache, slot)
-            tok = self._sample(logits, req.temperature)
-            req.out_tokens.append(int(tok[0]))
-            self.slots[slot] = req
-            self.positions[slot] = s
-            self.last_tokens[slot] = int(tok[0])
+            req.t_admit = time.perf_counter()
+            with TraceAnnotation("serve.admit", uid=req.uid, prompt_len=s,
+                                 slot=slot):
+                with TraceAnnotation("serve.init_cache", uid=req.uid):
+                    one_cache = self.model.init_cache(1, self.max_len)
+                with TraceAnnotation("serve.prefill", uid=req.uid):
+                    logits, one_cache = self._prefill1(
+                        self.params, {"tokens": jnp.asarray(req.prompt[None])},
+                        one_cache)
+                with TraceAnnotation("serve.insert", uid=req.uid):
+                    self.cache = _insert_slot(self.cache, one_cache, slot)
+                with TraceAnnotation("serve.first_token", uid=req.uid):
+                    tok = int(self._sample(logits, req.temperature)[0])
+                req.t_first = time.perf_counter()
+                req.out_tokens.append(tok)
+                self.slots[slot] = req
+                self.positions[slot] = s
+                self.last_tokens[slot] = tok
 
     def _sample(self, logits: jax.Array, temperature: float) -> np.ndarray:
         if temperature <= 0.0:
@@ -102,30 +133,35 @@ class ServeSession:
     def step(self) -> int:
         """Admit pending requests, decode one token for all live slots.
         Returns number of live slots."""
-        self._admit()
-        live = [i for i, r in enumerate(self.slots) if r is not None]
-        if not live:
-            return 0
-        tokens = jnp.asarray(self.last_tokens)
-        positions = jnp.asarray(self.positions)
-        logits, self.cache = self._decode(self.params, tokens, positions,
-                                          self.cache)
-        lg = np.asarray(logits, np.float32)
-        for slot in live:
-            req = self.slots[slot]
-            tok = self._sample(jnp.asarray(lg[slot : slot + 1]),
-                               req.temperature)[0]
-            req.out_tokens.append(int(tok))
-            self.positions[slot] += 1
-            self.last_tokens[slot] = int(tok)
-            hit_eos = self.eos_id is not None and int(tok) == self.eos_id
-            full = len(req.out_tokens) >= req.max_new_tokens or \
-                self.positions[slot] + 1 >= self.max_len
-            if hit_eos or full:
-                req.done = True
-                self.finished.append(req)
-                self.slots[slot] = None
-        return len(live)
+        with TraceAnnotation("serve.step"):
+            self._admit()
+            live = [i for i, r in enumerate(self.slots) if r is not None]
+            if not live:
+                return 0
+            with TraceAnnotation("serve.decode"):
+                tokens = jnp.asarray(self.last_tokens)
+                positions = jnp.asarray(self.positions)
+                logits, self.cache = self._decode(self.params, tokens,
+                                                  positions, self.cache)
+            with TraceAnnotation("serve.fetch"):
+                lg = np.asarray(logits, np.float32)
+            with TraceAnnotation("serve.sample"):
+                for slot in live:
+                    req = self.slots[slot]
+                    tok = self._sample(jnp.asarray(lg[slot : slot + 1]),
+                                       req.temperature)[0]
+                    req.out_tokens.append(int(tok))
+                    self.positions[slot] += 1
+                    self.last_tokens[slot] = int(tok)
+                    hit_eos = (self.eos_id is not None
+                               and int(tok) == self.eos_id)
+                    full = len(req.out_tokens) >= req.max_new_tokens or \
+                        self.positions[slot] + 1 >= self.max_len
+                    if hit_eos or full:
+                        req.done = True
+                        self.finished.append(req)
+                        self.slots[slot] = None
+            return len(live)
 
     def run_to_completion(self, max_steps: int = 10_000) -> list[Request]:
         for _ in range(max_steps):

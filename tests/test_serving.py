@@ -1,7 +1,10 @@
 """Serving engine: greedy parity with manual decode + continuous batching."""
+import glob
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_smoke_config
 from repro.core.engine import ArcaneEngine
@@ -71,3 +74,85 @@ def test_ragged_lengths_isolated(rng):
         return r.out_tokens
 
     assert run_with(other1) == run_with(other2)
+
+
+# ---------------------------------------------------------------- spans
+PHASES = ("serve.decode", "serve.fetch", "serve.sample")
+ADMIT_PHASES = ("serve.init_cache", "serve.prefill", "serve.insert",
+                "serve.first_token")
+
+
+@pytest.fixture(scope="module")
+def traced_session(tmp_path_factory):
+    """The same greedy requests served with the profiler off, then on; the
+    second run's ``serve.*`` host spans as (name, start, end, args)."""
+    from jax.profiler import ProfileData
+
+    cfg = get_smoke_config("stablelm-3b")
+    model = LM(cfg, ENGINE)
+    params = model.init_params(jax.random.key(0))
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, cfg.vocab, int(n)) for n in (5, 9, 3, 12, 6)]
+
+    def serve():
+        sess = ServeSession(model, params, max_slots=2, max_len=64)
+        reqs = [sess.submit(p, max_new_tokens=4) for p in prompts]
+        lives = []
+        while sess.pending or any(s is not None for s in sess.slots):
+            lives.append(sess.step())
+        return reqs, lives
+
+    off = serve()
+    out = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(out)):
+        on = serve()
+    path = sorted(glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True))
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in ProfileData.from_file(path[-1]).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("serve.")]
+    return off, on, spans
+
+
+def _inside(spans, outer, name):
+    return [s for s in spans if s[0] == name
+            and outer[1] <= s[1] and s[2] <= outer[2]]
+
+
+def test_step_spans_hold_one_of_each_phase(traced_session):
+    _, (_, lives), spans = traced_session
+    steps = sorted((s for s in spans if s[0] == "serve.step"),
+                   key=lambda s: s[1])
+    assert len(steps) == len(lives) and all(n > 0 for n in lives)
+    for st in steps:
+        for name in PHASES:
+            assert len(_inside(spans, st, name)) == 1, (name, st)
+    for name in PHASES:
+        assert sum(1 for s in spans if s[0] == name) == len(steps)
+
+
+def test_admission_spans_carry_uid_and_four_children(traced_session):
+    _, (reqs, _), spans = traced_session
+    for r in reqs:
+        admits = [s for s in spans
+                  if s[0] == "serve.admit" and s[3].get("uid") == r.uid]
+        assert len(admits) == 1, r.uid
+        adm = admits[0]
+        assert adm[3]["prompt_len"] == len(r.prompt)
+        for name in ADMIT_PHASES:
+            kids = _inside(spans, adm, name)
+            assert len(kids) == 1 and kids[0][3]["uid"] == r.uid, name
+    assert sum(1 for s in spans if s[0] == "serve.admit") == len(reqs)
+
+
+def test_admission_times_ordered(traced_session):
+    (reqs_off, _), (reqs_on, _), _ = traced_session
+    for r in reqs_off + reqs_on:
+        assert r.t_admit is not None and r.t_admit <= r.t_first
+
+
+def test_profiler_leaves_tokens_unchanged(traced_session):
+    (reqs_off, lives_off), (reqs_on, lives_on), _ = traced_session
+    assert lives_off == lives_on
+    assert [r.out_tokens for r in reqs_off] == [r.out_tokens for r in reqs_on]
+    assert all(len(r.out_tokens) == 4 for r in reqs_on)
