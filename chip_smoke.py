@@ -147,15 +147,18 @@ def kernel_counts(model, params, *, prompt_len: int = PROMPT_LENS[1],
                   slots: int = SLOTS, max_len: int = MAX_LEN) -> dict:
     """``tpu_custom_call`` count in the compiled prefill and decode.
 
-    Compiles what ``ServeSession`` compiles (``jax.jit`` of the model's own
-    methods at the served shapes), so the persistent cache serves it."""
+    Compiles what ``ServeSession`` compiles (``jax.jit`` of the model's
+    prefill, and of the serve decode step with its cache donated, at the
+    served shapes), so the persistent cache serves it."""
     import jax
     import jax.numpy as jnp
+    from repro.train.step import make_serve_steps
     tok = jax.ShapeDtypeStruct
     prefill = jax.jit(model.prefill).lower(
         params, {"tokens": tok((1, prompt_len), jnp.int32)},
         model.cache_shapes(1, max_len)).compile().as_text()
-    decode = jax.jit(model.decode_step).lower(
+    _, decode_step = make_serve_steps(model)
+    decode = jax.jit(decode_step, donate_argnums=(3,)).lower(
         params, tok((slots,), jnp.int32), tok((slots,), jnp.int32),
         model.cache_shapes(slots, max_len)).compile().as_text()
     return {"prefill": prefill.count("tpu_custom_call"),

@@ -158,14 +158,22 @@ class ArcaneEngine:
             block_k=self.attn_block_k, backend=self._attn_backend,
             interpret=self.interpret)
 
-    def decode_attention(self, q, k, v, lengths, *, softcap=None,
+    def decode_attention(self, q, k, v, lengths, *, layer=None, softcap=None,
                          scale=None, window=None) -> jax.Array:
+        """k, v: one layer's cache (B, Hkv, S, D), or the stack of every
+        layer's (L, B, Hkv, D, S) with ``layer`` naming the one to read."""
         b, hq, d = q.shape
-        s = k.shape[2]
+        s = k.shape[-1] if layer is not None else k.shape[-2]
         self._log(6, q.dtype, (q.shape, k.shape), 4 * b * hq * s * d)
-        return kernels.decode_attention(q, k, v, lengths, softcap=softcap,
-                                        scale=scale, window=window,
-                                        block_k=self.attn_block_k,
-                                        backend=self.backend,
+        return kernels.decode_attention(q, k, v, lengths, layer,
+                                        softcap=softcap, scale=scale,
+                                        window=window, backend=self.backend,
                                         interpret=self.interpret)
 
+    def kv_write(self, cache_k, cache_v, new_k, new_v, slot, layer):
+        """Each sequence's new K/V column into layer ``layer`` of the
+        stacked (L, B, Hkv, D, S) caches at ``slot``; new_*: (B, Hkv, D, 1).
+        """
+        return kernels.kv_write(cache_k, cache_v, new_k, new_v, slot, layer,
+                                backend=self.backend,
+                                interpret=self.interpret)

@@ -184,11 +184,11 @@ def cache_pspecs(cache: PyTree, mesh: Mesh) -> PyTree:
             ax = ax[0]
         spec[1] = ax
         if re.search(r"(^|/)(k|v|xk|xv)$", ps):
-            # (L, B, Hkv, S, hd)
+            # (L, B, Hkv, hd, S): models/attention.py's cache layout
             if _fits(shape[2], mesh, "model"):
                 spec[2] = "model"
-            elif _fits(shape[3], mesh, "model"):
-                spec[3] = "model"
+            elif _fits(shape[4], mesh, "model"):
+                spec[4] = "model"
         elif re.search(r"/(c|kr)$", ps):           # MLA latent (L, B, S, r)
             if _fits(shape[2], mesh, "model"):
                 spec[2] = "model"

@@ -9,6 +9,7 @@ from repro.kernels.maxpool.ops import maxpool
 from repro.kernels.leakyrelu.ops import leakyrelu
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.decode_attention.ops import decode_attention
+from repro.kernels.kv_write.ops import kv_write
 
 __all__ = ["gemm", "conv_layer", "maxpool", "leakyrelu", "flash_attention",
-           "decode_attention"]
+           "decode_attention", "kv_write"]

@@ -8,9 +8,23 @@ materialisation: the q-head group belonging to one KV head attends inside a
 single program.
 
 q: (B, Hkv, G, D)  — G = Hq / Hkv query heads per KV head,
-k, v: (B, Hkv, S, D) — the cache, padded to the page multiple,
+k, v: (B, Hkv, S, D) — one layer's cache, or
+      (L, B, Hkv, D, S) — every layer's cache, stacked and stored with the
+      sequence minor, as the model keeps its K/V cache; the kernel reads
+      layer ``layer`` in place, so no copy of the layer is made,
 lengths: (B,) int32 — valid cache length per sequence (ragged batch), held
-in SMEM through scalar prefetch and read as a scalar per program.
+in SMEM through scalar prefetch and read as a scalar per program; ``layer``
+rides beside it and is used only by the K/V index maps.
+
+Why sequence-minor: a TPU tiles the two minor dims of an array by (8, 128).
+A (S, D) page with D = 80 would pad D to 128 lanes, so XLA keeps such an
+array transposed in HBM and any kernel reading (S, D) blocks forces a
+relayout copy of the whole layer. (D, S) is dense as it stands.
+
+Pages are ``block_k`` positions, or all of S when it is shorter. Where
+``block_k`` does not divide S the last page runs past the cache's end: the
+DMA reads only what lies inside, the rest of the block is undefined, so the
+kernel masks V there as it masks the scores. The cache is never padded.
 
 Grid: (B, Hkv, pages); per-page blocks are skipped entirely once past the
 sequence length (`pl.when`), so short sequences in a ragged batch cost only
@@ -26,12 +40,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import NEG_INF, interpret_default, round_up
+from repro.kernels.common import NEG_INF, interpret_default
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                   *, nkv: int, bk: int, scale: float,
-                   softcap: Optional[float], window: Optional[int]):
+def _decode_kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
+                   m_ref, l_ref, *, nkv: int, bk: int, scale: float,
+                   softcap: Optional[float], window: Optional[int],
+                   seq_minor: bool, ragged: bool):
     ik = pl.program_id(2)
     length = len_ref[pl.program_id(0)]
     start = jnp.maximum(length - window, 0) if window is not None else 0
@@ -45,9 +60,14 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     @pl.when(jnp.logical_and(ik * bk < length, (ik + 1) * bk > start))
     def _update():
         q = q_ref[0, 0].astype(jnp.float32) * scale      # (G, d)
-        k = k_ref[0, 0].astype(jnp.float32)              # (bk, d)
+        k = k_ref[0, 0].astype(jnp.float32)   # (bk, d), (d, bk) if seq_minor
         v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        kd = 0 if seq_minor else 1                       # k's d axis
+        if ragged:      # the last page holds undefined data past the cache
+            pos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape,
+                                                      1 - kd)
+            v = jnp.where(pos < length, v, 0.0)
+        s = jax.lax.dot_general(q, k, (((1,), (kd,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (G, bk)
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
@@ -60,7 +80,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((1,), (1 - kd,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ik == nkv - 1)
@@ -75,39 +96,52 @@ def decode_attention_pallas(
     v: jax.Array,
     lengths: jax.Array,
     *,
+    layer: Optional[jax.Array] = None,
     softcap: Optional[float] = None,
     scale: Optional[float] = None,
     window: Optional[int] = None,
     block_k: int = 512,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """q: (B, Hkv, G, D); k, v: (B, Hkv, S, D); lengths: (B,) → (B, Hkv, G, D)."""
+    """q: (B, Hkv, G, D); k, v: (B, Hkv, S, D), or (L, B, Hkv, D, S) with a
+    scalar ``layer``; lengths: (B,) → (B, Hkv, G, D)."""
     if interpret is None:
         interpret = interpret_default()
+    seq_minor = k.ndim == 5
+    if not seq_minor:
+        # one layer: a leading axis of 1 is a bitcast, not a copy
+        k, v, layer = k[None], v[None], 0
     b, hkv, g, d = q.shape
-    _, _, s, _ = k.shape
+    s = k.shape[4] if seq_minor else k.shape[3]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    bk = min(block_k, round_up(s, 8))
-    sp = round_up(s, bk)
-    if sp != s:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
-    nkv = sp // bk
+    # a page is a block's lane dim when the sequence is minor, else sublanes
+    align = 128 if seq_minor else 8
+    if block_k % align:
+        raise ValueError(f"block_k must be a multiple of {align}")
+    bk = min(block_k, s)
+    nkv = pl.cdiv(s, bk)
 
     kernel = functools.partial(_decode_kernel, nkv=nkv, bk=bk, scale=scale,
-                               softcap=softcap, window=window)
-    # index maps take the prefetched lengths as a trailing (unused) argument
+                               softcap=softcap, window=window,
+                               seq_minor=seq_minor, ragged=s % bk != 0)
+    # index maps take the prefetched lengths and layer as trailing arguments
+    if seq_minor:
+        kv_spec = pl.BlockSpec((None, 1, 1, d, bk),
+                               lambda bb, h, ik, ln, ly: (ly[0], bb, h, 0, ik))
+    else:
+        kv_spec = pl.BlockSpec((None, 1, 1, bk, d),
+                               lambda bb, h, ik, ln, ly: (ly[0], bb, h, ik, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, hkv, nkv),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bb, h, ik, ln: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bb, h, ik, ln: (bb, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bb, h, ik, ln: (bb, h, ik, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda bb, h, ik, ln, ly: (bb, h, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bb, h, ik, ln: (bb, h, 0, 0)),
+                               lambda bb, h, ik, ln, ly: (bb, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, d), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
@@ -121,4 +155,5 @@ def decode_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.reshape(b).astype(jnp.int32), q, k, v)
+    )(lengths.reshape(b).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, k, v)

@@ -31,6 +31,27 @@ def attention_init(key, cfg: ModelConfig, *, cross: bool = False) -> dict:
     }
 
 
+# ---------------------------------------------------------- cache layout
+# Attention K/V caches, self and cross, keep the sequence on the last axis:
+# (B, Hkv, hd, S) for one layer, (L, B, Hkv, hd, S) stacked. A TPU tiles an
+# array's two minor dims by (8, 128): a (S, hd) cache with hd = 80 would pad
+# hd to 128 lanes, so XLA keeps it transposed in HBM and every row-major
+# read of a layer is a relayout copy; (hd, S) is dense as it stands, and the
+# decode kernel reads a layer where it lies (kernels/decode_attention).
+def kv_cache_shape(batch: int, n_kv: int, hd: int, s: int) -> tuple:
+    return (batch, n_kv, hd, s)
+
+
+def kv_cache_len(cache: jax.Array) -> int:
+    """Positions a K/V cache holds, per layer or stacked."""
+    return cache.shape[-1]
+
+
+def to_cache_layout(x: jax.Array) -> jax.Array:
+    """(B, Hkv, S, hd) heads as attention computes them → cache layout."""
+    return jnp.swapaxes(x, -1, -2)
+
+
 def _split_heads(x: jax.Array, n: int) -> jax.Array:
     b, s, _ = x.shape
     out = x.reshape(b, s, n, -1).transpose(0, 2, 1, 3)   # (B, H, S, D)
@@ -83,9 +104,11 @@ def attention_prefill(
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Prefill: forward + write K/V into the cache at [0, S).
 
+    cache_k, cache_v: (B, Hkv, hd, S_cache), sequence minor.
+
     Ring mode (window-sized cache for local layers, §Perf iteration 5): only
-    the last ``window`` rows are kept, placed at slot ``pos % window`` — a
-    static permutation because S and window are static.
+    the last ``window`` positions are kept, placed at slot ``pos % window``
+    — a static permutation because S and window are static.
     """
     b, s, _ = x.shape
     q = _split_heads(dense(engine, params["q"], x), cfg.n_heads)
@@ -93,22 +116,26 @@ def attention_prefill(
     v = _split_heads(dense(engine, params["v"], x), cfg.n_kv_heads)
     q = apply_rope(q, positions, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
     k = apply_rope(k, positions, theta=cfg.rope_theta, fraction=cfg.rope_fraction)
+    # K and V go two ways, into flash attention and transposed into the
+    # cache; computed once here, else XLA computes the rotation in both,
+    # and where the flash tile does not divide the prompt the compiled
+    # prefill then kept the FFN activations out of VMEM (1920 tokens,
+    # stablelm-3b on a v5e: 602.8 ms a prefill, 535.0 with the barrier)
+    k, v = jax.lax.optimization_barrier((k, v))
     out = engine.attention(q, k, v, causal=True, window=window,
                            softcap=cfg.attn_softcap)
+    kt = to_cache_layout(k).astype(cache_k.dtype)
+    vt = to_cache_layout(v).astype(cache_v.dtype)
     if ring:
-        w = cache_k.shape[2]
+        w = kv_cache_len(cache_k)
         keep = min(w, s)
         pos_tail = jnp.arange(s - keep, s)
         slots = pos_tail % w                      # static permutation
-        cache_k = cache_k.at[:, :, slots, :].set(
-            k[:, :, s - keep:, :].astype(cache_k.dtype))
-        cache_v = cache_v.at[:, :, slots, :].set(
-            v[:, :, s - keep:, :].astype(cache_v.dtype))
+        cache_k = cache_k.at[..., slots].set(kt[..., s - keep:])
+        cache_v = cache_v.at[..., slots].set(vt[..., s - keep:])
     else:
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k.astype(cache_k.dtype), (0, 0, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v.astype(cache_v.dtype), (0, 0, 0, 0))
+        cache_k = jax.lax.dynamic_update_slice(cache_k, kt, (0, 0, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(cache_v, vt, (0, 0, 0, 0))
     return dense(engine, params["o"], _merge_heads(out)), cache_k, cache_v
 
 
@@ -120,15 +147,19 @@ def attention_decode(
     position: jax.Array,
     cache_k: jax.Array,
     cache_v: jax.Array,
+    layer: jax.Array,
     *,
     window: Optional[int] = None,
     ring: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """One-token decode. x: (B, d); position: (B,) current index.
 
-    The new K/V row is written into the cache, then the decode kernel sweeps
-    the cache in place. Sliding-window layers bound the sweep length via the
-    kv length argument (cache is ring-buffered by the serving layer).
+    cache_k, cache_v: every layer's cache, stacked (L, B, Hkv, hd, S). The
+    new K/V columns are written in place at ``layer``, one per sequence, then
+    the decode kernel reads that layer straight from the stack: no op
+    outside the kernel touches a whole layer's cache. Sliding-window layers
+    bound the sweep length via the kv length argument (cache is
+    ring-buffered by the serving layer).
     """
     b, d = x.shape
     hd = cfg.resolved_head_dim
@@ -143,23 +174,17 @@ def attention_decode(
                    fraction=cfg.rope_fraction)
     v = _split_heads(v, cfg.n_kv_heads)
 
-    # scatter the new row at per-sequence positions (ring: pos % window —
+    # write the new columns at per-sequence positions (ring: pos % window —
     # the ring holds exactly the window, so no extra masking is needed and
     # the softmax is order-independent)
-    w = cache_k.shape[2]
+    w = kv_cache_len(cache_k)
     slot = position % w if ring else position
-
-    def put(cache, new):
-        # cache: (B, Hkv, S, hd); new: (B, Hkv, 1, hd)
-        return jax.vmap(
-            lambda c, n, p: jax.lax.dynamic_update_slice(c, n, (0, p, 0))
-        )(cache, new.astype(cache.dtype), slot)
-
-    cache_k = put(cache_k, k)
-    cache_v = put(cache_v, v)
+    cache_k, cache_v = engine.kv_write(
+        cache_k, cache_v, to_cache_layout(k), to_cache_layout(v),
+        slot, layer)                                     # new: (B,Hkv,hd,1)
     lengths = jnp.minimum(position + 1, w) if ring else position + 1
     out = engine.decode_attention(q[:, :, 0, :], cache_k, cache_v, lengths,
-                                  softcap=cfg.attn_softcap,
+                                  layer=layer, softcap=cfg.attn_softcap,
                                   window=None if ring else window)  # (B,Hq,hd)
     out = dense(engine, params["o"], out.reshape(b, cfg.n_heads * hd))
     return out, cache_k, cache_v

@@ -108,11 +108,12 @@ def init_block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
         if (spec.kind == "attn_local" and cfg.ring_local_cache
                 and cfg.local_window and cfg.local_window < max_len):
             s_len = cfg.local_window          # ring buffer (§Perf iter. 5)
-        c = {"k": jnp.zeros((batch, cfg.n_kv_heads, s_len, hd), dtype),
-             "v": jnp.zeros((batch, cfg.n_kv_heads, s_len, hd), dtype)}
+        shape = attn.kv_cache_shape(batch, cfg.n_kv_heads, hd, s_len)
+        c = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
         if cross_len:
-            c["xk"] = jnp.zeros((batch, cfg.n_kv_heads, cross_len, hd), dtype)
-            c["xv"] = jnp.zeros((batch, cfg.n_kv_heads, cross_len, hd), dtype)
+            xshape = attn.kv_cache_shape(batch, cfg.n_kv_heads, hd, cross_len)
+            c["xk"] = jnp.zeros(xshape, dtype)
+            c["xv"] = jnp.zeros(xshape, dtype)
         return c
     if spec.kind == "mla":
         m = cfg.mla
@@ -140,7 +141,7 @@ def block_prefill(engine, params, cfg, spec, x, positions, cache, *,
     if spec.kind in ("attn", "attn_local"):
         window = cfg.local_window if spec.kind == "attn_local" else None
         ring = (window is not None and cfg.ring_local_cache
-                and cache["k"].shape[2] == window)
+                and attn.kv_cache_len(cache["k"]) == window)
         h, cache["k"], cache["v"] = attn.attention_prefill(
             engine, params["attn"], cfg, h, positions, cache["k"], cache["v"],
             window=window, ring=ring)
@@ -176,8 +177,8 @@ def block_prefill(engine, params, cfg, spec, x, positions, cache, *,
             attn.dense(engine, params["cross"]["k"], enc_out), cfg.n_kv_heads)
         vx = attn._split_heads(
             attn.dense(engine, params["cross"]["v"], enc_out), cfg.n_kv_heads)
-        cache["xk"], cache["xv"] = kx.astype(cache["xk"].dtype), \
-            vx.astype(cache["xv"].dtype)
+        cache["xk"] = attn.to_cache_layout(kx).astype(cache["xk"].dtype)
+        cache["xv"] = attn.to_cache_layout(vx).astype(cache["xv"].dtype)
         x = x + attn.attention_forward(
             engine, params["cross"], cfg, hc, positions, causal=False,
             kv_override=(kx, vx))
@@ -186,18 +187,54 @@ def block_prefill(engine, params, cfg, spec, x, positions, cache, *,
     return x + h, cache
 
 
-def block_decode(engine, params, cfg, spec, x, position, cache, *,
+def decode_inplace_leaves(spec: LayerSpec) -> frozenset:
+    """The cache leaves that the decode step updates in place in the stack.
+
+    Self-attention (``attn``, ``attn_local``, ring or not) adds one K and
+    one V column per sequence, so it writes them straight into the
+    (n_periods, B, Hkv, hd, S) stack, and the decode kernel reads its layer
+    from there. Every other leaf (MLA's latent rows, mamba and rwkv state,
+    the read-only cross-attention K/V) is taken out per layer and put back.
+    """
+    if spec.kind in ("attn", "attn_local"):
+        return frozenset(("k", "v"))
+    return frozenset()
+
+
+def block_decode(engine, params, cfg, spec, x, position, cache, layer, *,
                  enc_len: Optional[int] = None):
-    """One-token step. x: (B, d); returns (x, cache)."""
+    """One-token step. x: (B, d); cache: this pattern position's leaves for
+    every period, stacked (n_periods, ...); layer: the period to run.
+    Returns (x, cache) with the stack updated at ``layer``."""
+    inplace = decode_inplace_leaves(spec)
+    one = {n: c if n in inplace
+           else jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+           for n, c in cache.items()}
+    x, new = _block_decode_layer(engine, params, cfg, spec, x, position,
+                                 dict(one), layer, enc_len=enc_len)
+    out = {}
+    for n, c in cache.items():
+        if n in inplace:
+            out[n] = new[n]
+        elif new[n] is one[n]:          # read only: nothing to put back
+            out[n] = c
+        else:
+            out[n] = jax.lax.dynamic_update_index_in_dim(c, new[n], layer, 0)
+    return x, out
+
+
+def _block_decode_layer(engine, params, cfg, spec, x, position, cache, layer,
+                        *, enc_len: Optional[int] = None):
+    """One layer's decode; the in-place leaves are still the whole stack."""
     _, napply = _norm(cfg)
     h = napply(params["ln1"], x)
     if spec.kind in ("attn", "attn_local"):
         window = cfg.local_window if spec.kind == "attn_local" else None
         ring = (window is not None and cfg.ring_local_cache
-                and cache["k"].shape[2] == window)
+                and attn.kv_cache_len(cache["k"]) == window)
         h, cache["k"], cache["v"] = attn.attention_decode(
             engine, params["attn"], cfg, h, position, cache["k"], cache["v"],
-            window=window, ring=ring)
+            layer, window=window, ring=ring)
     elif spec.kind == "mla":
         h, cache["c"], cache["kr"] = mla_mod.mla_decode(
             engine, params["attn"], cfg, h, position, cache["c"], cache["kr"])
@@ -220,8 +257,9 @@ def block_decode(engine, params, cfg, spec, x, position, cache, *,
         q = attn.dense(engine, params["cross"]["q"], hc[:, None, :])
         q = attn._split_heads(q, cfg.n_heads)[:, :, 0, :]       # (B,Hq,hd)
         lengths = jnp.full((b,), enc_len, jnp.int32)
-        o = engine.decode_attention(q, cache["xk"], cache["xv"], lengths,
-                                    softcap=cfg.attn_softcap)
+        # this layer's cross K/V, as a stack of one
+        o = engine.decode_attention(q, cache["xk"][None], cache["xv"][None],
+                                    lengths, layer=0, softcap=cfg.attn_softcap)
         o = attn.dense(engine, params["cross"]["o"],
                        o.reshape(b, cfg.n_heads * cfg.resolved_head_dim))
         x = x + o

@@ -229,17 +229,23 @@ class LM:
             x = x + sinusoidal_at(position, cfg.d_model).astype(x.dtype)
         x = x.astype(cfg.cdtype)
 
-        def period_fn(h, xs):
-            bps, caches = xs
+        # The stacked cache rides in the carry, so each layer updates it in
+        # place (see blocks.block_decode); only params and the layer index
+        # are scanned over.
+        def period_fn(carry, xs):
+            h, caches = carry
+            bps, layer = xs
             new_caches = []
             for i, spec in enumerate(cfg.pattern):
                 h, c = blk.block_decode(self.engine, bps[i], cfg, spec, h,
-                                        position, caches[i],
+                                        position, caches[i], layer,
                                         enc_len=enc_len or None)
                 new_caches.append(c)
-            return h, tuple(new_caches)
+            return (h, tuple(new_caches)), None
 
-        x, cache = self._scan(period_fn, x, (params["blocks"], cache))
+        (x, cache), _ = self._scan(
+            period_fn, (x, cache),
+            (params["blocks"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
         _, napply = make_norm(cfg.norm)
         x = napply(params["final_norm"], x)
         table = params["unembed" if "unembed" in params else "embed"]

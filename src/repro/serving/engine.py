@@ -19,11 +19,16 @@ off, and change nothing that is jitted::
     │  ├─ serve.prefill              dispatch of the jitted prefill
     │  ├─ serve.insert               the copy into the batched cache
     │  └─ serve.first_token          sampling the first token
-    ├─ serve.decode                  dispatch of the jitted decode step
+    ├─ serve.decode  (inplace_share) dispatch of the jitted decode step
     ├─ serve.fetch                   logits to the host
     └─ serve.sample                  per-slot sampling and bookkeeping
 
-The admission spans carry the request's ``uid``.
+The admission spans carry the request's ``uid``. ``serve.decode`` carries
+``inplace_share``: the share of the cache's bytes that the decode step
+updates in place (``decode_inplace_share``).
+
+The decode step donates the batched cache: each step's cache is written in
+place, and the previous one is gone once ``step()`` returns.
 """
 from __future__ import annotations
 
@@ -36,7 +41,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.models.blocks import decode_inplace_leaves
 from repro.models.transformer import LM
+from repro.train.step import make_serve_steps
 
 PyTree = Any
 
@@ -66,6 +73,20 @@ def _insert_slot(batched: PyTree, one: PyTree, slot: int) -> PyTree:
     return jax.tree.map(put, batched, one)
 
 
+def decode_inplace_share(model: LM, cache_shapes: tuple) -> float:
+    """Share of the cache's bytes that ``LM.decode_step`` updates in place,
+    from the shapes of a cache (``LM.cache_shapes``); the leaves that take
+    the in-place path are those ``blocks.decode_inplace_leaves`` names."""
+    total = inplace = 0
+    for spec, leaves in zip(model.cfg.pattern, cache_shapes):
+        names = decode_inplace_leaves(spec)
+        for name, leaf in leaves.items():
+            nbytes = leaf.size * leaf.dtype.itemsize
+            total += nbytes
+            inplace += nbytes if name in names else 0
+    return inplace / total if total else 0.0
+
+
 class ServeSession:
     def __init__(self, model: LM, params: PyTree, *, max_slots: int = 4,
                  max_len: int = 512, eos_id: Optional[int] = None,
@@ -81,8 +102,11 @@ class ServeSession:
         self.last_tokens = np.zeros((max_slots,), np.int32)
         self._uid = 0
         self._key = jax.random.key(seed)
+        self.decode_inplace_share = decode_inplace_share(
+            model, model.cache_shapes(max_slots, max_len))
         self._prefill1 = jax.jit(model.prefill)
-        self._decode = jax.jit(model.decode_step)
+        _, decode_step = make_serve_steps(model)
+        self._decode = jax.jit(decode_step, donate_argnums=(3,))
         self.pending: list[Request] = []
         self.finished: list[Request] = []
 
@@ -138,7 +162,8 @@ class ServeSession:
             live = [i for i, r in enumerate(self.slots) if r is not None]
             if not live:
                 return 0
-            with TraceAnnotation("serve.decode"):
+            with TraceAnnotation("serve.decode",
+                                 inplace_share=self.decode_inplace_share):
                 tokens = jnp.asarray(self.last_tokens)
                 positions = jnp.asarray(self.positions)
                 logits, self.cache = self._decode(self.params, tokens,

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import (conv_layer, decode_attention, flash_attention,
-                           gemm, leakyrelu, maxpool)
+                           gemm, kv_write, leakyrelu, maxpool)
 from repro.kernels.convlayer.ref import conv_layer_ref
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.ref import (attention_chunked_ref,
@@ -128,17 +128,60 @@ def test_flash_attention_bf16(rng):
 
 
 # -------------------------------------------------------- decode attention
-@pytest.mark.parametrize("window", [None, 50, 16])
-def test_decode_attention_sweep(rng, window):
-    B, Hq, Hkv, S, D = 2, 8, 2, 200, 64
-    lengths = jnp.array([37, 190])
-    k = jnp.array(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
-    v = jnp.array(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
+SWEEP = [pytest.param(w, None, 200, id=str(w)) for w in (None, 50, 16)] + [
+    # every layer of a stack (L, B, Hkv, D, S) read in place, against the
+    # one-layer call on its slice; at S = 640 the last of three 256-position
+    # pages runs past the cache
+    pytest.param(w, 3, s, id=f"stacked-{w}-{s}")
+    for w in (None, 50, 16) for s in (200, 640)]
+
+
+@pytest.mark.parametrize("window,layers,S", SWEEP)
+def test_decode_attention_sweep(rng, window, layers, S):
+    B, Hq, Hkv, D = 2, 8, 2, 64
+    lengths = jnp.array([37, S - 10])
     q = jnp.array(rng.standard_normal((B, Hq, D)), jnp.float32)
-    out = decode_attention(q, k, v, lengths, window=window, block_k=64)
-    ref = decode_attention_ref(q.reshape(B, Hkv, Hq // Hkv, D), k, v, lengths,
-                               window=window).reshape(B, Hq, D)
-    np.testing.assert_allclose(out, ref, atol=2e-3, rtol=1e-3)
+    if layers is None:
+        k = jnp.array(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
+        v = jnp.array(rng.standard_normal((B, Hkv, S, D)), jnp.float32)
+        out = decode_attention(q, k, v, lengths, window=window, block_k=64)
+        ref = decode_attention_ref(q.reshape(B, Hkv, Hq // Hkv, D), k, v,
+                                   lengths, window=window).reshape(B, Hq, D)
+        np.testing.assert_allclose(out, ref, atol=2e-3, rtol=1e-3)
+        return
+    ks = jnp.array(rng.standard_normal((layers, B, Hkv, D, S)), jnp.float32)
+    vs = jnp.array(rng.standard_normal((layers, B, Hkv, D, S)), jnp.float32)
+    for layer in range(layers):
+        k, v = (jnp.swapaxes(c[layer], 2, 3) for c in (ks, vs))
+        want = decode_attention(q, k, v, lengths, window=window, block_k=64)
+        for backend in ("pallas", "ref"):
+            got = decode_attention(q, ks, vs, lengths, jnp.int32(layer),
+                                   window=window, block_k=256,
+                                   backend=backend)
+            np.testing.assert_allclose(got, want, atol=2e-3, rtol=1e-3)
+
+
+@pytest.mark.parametrize("S", [64, 200, 384])
+def test_kv_write_matches_ref(rng, S):
+    """Each sequence's column lands at its slot of the named layer, and
+    nothing else moves; S = 384 writes in three 128-lane blocks, and at
+    S = 200 the second block runs past the cache."""
+    L, B, Hkv, D = 3, 4, 2, 16
+    ks = jnp.array(rng.standard_normal((L, B, Hkv, D, S)), jnp.float32)
+    vs = jnp.array(rng.standard_normal((L, B, Hkv, D, S)), jnp.float32)
+    nk = jnp.array(rng.standard_normal((B, Hkv, D, 1)), jnp.float32)
+    nv = jnp.array(rng.standard_normal((B, Hkv, D, 1)), jnp.float32)
+    slot = jnp.array([0, S - 1, 127 % S, 130 % S], jnp.int32)
+    for layer in range(L):
+        got = kv_write(ks, vs, nk, nv, slot, jnp.int32(layer))
+        want = kv_write(ks, vs, nk, nv, slot, jnp.int32(layer),
+                        backend="ref")
+        for g, w, old, new in zip(got, want, (ks, vs), (nk, nv)):
+            np.testing.assert_array_equal(g, w)
+            np.testing.assert_array_equal(
+                g[layer, jnp.arange(B), :, :, slot], new[..., 0])
+            changed = np.asarray(g != old).any(axis=(2, 3))
+            assert changed.sum() <= B and not changed[:layer].any()
 
 
 def test_decode_attention_mha_and_softcap(rng):

@@ -1,5 +1,6 @@
 """Per-arch smoke tests (deliverable f) + the golden incremental-decode test."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -8,6 +9,8 @@ import pytest
 
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.core.engine import ArcaneEngine
+from repro.models import blocks as blk
+from repro.models.layers import embed, make_norm, sinusoidal_at, unembed
 from repro.models.transformer import LM
 from repro.optim.adamw import AdamWConfig, adamw_init
 from repro.train.step import make_train_step
@@ -135,7 +138,7 @@ def test_ring_decode_matches_forward(rng):
     logits_full, _ = jax.jit(model.forward)(params, {"tokens": toks})
     P = S - 8
     cache = model.init_cache(B, 64, dtype=jnp.float32)
-    assert cache[0]["k"].shape[3] == 8      # local layer ring is window-sized
+    assert cache[0]["k"].shape[4] == 8      # local layer ring is window-sized
     lg, cache = jax.jit(model.prefill)(params, {"tokens": toks[:, :P]}, cache)
     errs = [float(jnp.max(jnp.abs(lg - logits_full[:, P - 1])))]
     step = jax.jit(model.decode_step)
@@ -145,3 +148,97 @@ def test_ring_decode_matches_forward(rng):
         if i < S - 1:
             errs.append(float(jnp.max(jnp.abs(lg - logits_full[:, i]))))
     assert max(errs) < 2e-3, errs
+
+
+class SlicedCacheEngine(ArcaneEngine):
+    """The K/V write and read as they were before the cache was carried:
+    each new column goes into the layer's own sliced cache with a plain
+    ``.at[].set`` per sequence, and attention reads a row-major copy of the
+    layer (``decode_attention_ref``, or the one-layer kernel call on the
+    pallas path). Shares no write or read path with the in-place step."""
+
+    def kv_write(self, cache_k, cache_v, new_k, new_v, slot, layer):
+        def put(cache, new):
+            for i in range(cache.shape[1]):
+                cache = cache.at[layer, i, :, :, slot[i]].set(new[i, ..., 0])
+            return cache
+        return put(cache_k, new_k), put(cache_v, new_v)
+
+    def decode_attention(self, q, k, v, lengths, *, layer=None, **kw):
+        if layer is not None:
+            k, v = (jnp.swapaxes(c[layer], -1, -2) for c in (k, v))
+        return super().decode_attention(q, k, v, lengths, **kw)
+
+
+def decode_step_xs_ys(model, params, tokens, position, cache, *, enc_len=0):
+    """The decode step as it was before the cache rode in the scan's carry:
+    each layer's cache is sliced out of the scan's ``xs`` and its new cache
+    stacked into ``ys``. Each block sees a one-layer stack at layer 0, and
+    the K/V go through ``SlicedCacheEngine``."""
+    cfg = model.cfg
+    engine = SlicedCacheEngine(model.engine.backend)
+    x = embed(params["embed"], tokens, scale=cfg.embed_scale)
+    if cfg.enc_dec:
+        x = x + sinusoidal_at(position, cfg.d_model).astype(x.dtype)
+    x = x.astype(cfg.cdtype)
+
+    def period_fn(h, xs):
+        bps, caches = xs
+        new = []
+        for i, spec in enumerate(cfg.pattern):
+            one = jax.tree.map(lambda c: c[None], caches[i])
+            h, c = blk.block_decode(engine, bps[i], cfg, spec, h,
+                                    position, one, 0,
+                                    enc_len=enc_len or None)
+            new.append(jax.tree.map(lambda c: c[0], c))
+        return h, tuple(new)
+
+    x, cache = jax.lax.scan(period_fn, x, (params["blocks"], cache))
+    _, napply = make_norm(cfg.norm)
+    x = napply(params["final_norm"], x)
+    table = params["unembed" if "unembed" in params else "embed"]
+    return unembed(engine, table, x, softcap=cfg.final_softcap), cache
+
+
+INPLACE_CASES = [
+    ("stablelm-3b", {}, "ref"),
+    ("stablelm-3b", {}, "pallas"),
+    ("gemma2-9b", {"ring_local_cache": True, "local_window": 8}, "ref"),
+    ("gemma2-9b", {"ring_local_cache": True, "local_window": 8}, "pallas"),
+    ("minicpm3-4b", {}, "ref"),
+    ("jamba-1.5-large-398b", {}, "ref"),
+    ("whisper-large-v3", {}, "ref"),
+]
+
+
+@pytest.mark.parametrize(
+    "arch,over,backend", INPLACE_CASES,
+    ids=[f"{a}-{b}{'-ring' if o else ''}" for a, o, b in INPLACE_CASES])
+def test_inplace_decode_matches_xs_ys(arch, over, backend):
+    """The carried, in-place decode step computes what the xs/ys scan did:
+    logits within 1e-5 and bit-identical caches, over several steps at
+    ragged positions (past the ring's window where there is one)."""
+    cfg = dataclasses.replace(get_smoke_config(arch), param_dtype="float32",
+                              compute_dtype="float32", **over)
+    model = LM(cfg, ArcaneEngine(backend=backend))
+    params = model.init_params(jax.random.key(3))
+    B, max_len = 3, 24
+    enc = 8 if cfg.enc_dec else 0
+    shapes = model.cache_shapes(B, max_len, dtype=jnp.float32, enc_len=enc)
+    keys = iter(jax.random.split(jax.random.key(4), 64))
+    cache = jax.tree.map(
+        lambda s: 0.5 * jax.random.normal(next(keys), s.shape, s.dtype),
+        shapes)
+    carried = jax.jit(functools.partial(model.decode_step, enc_len=enc))
+    xs_ys = jax.jit(functools.partial(decode_step_xs_ys, model, enc_len=enc))
+    position = jnp.array([5, 17, 11], jnp.int32)
+    rng = np.random.default_rng(5)
+    want_cache = cache
+    for _ in range(3):
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab, B), jnp.int32)
+        got, cache = carried(params, tokens, position, cache)
+        want, want_cache = xs_ys(params, tokens, position, want_cache)
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
+        for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
+            np.testing.assert_array_equal(a, b)
+        position = position + 1

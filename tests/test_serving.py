@@ -1,5 +1,6 @@
 """Serving engine: greedy parity with manual decode + continuous batching."""
 import glob
+import re
 
 import jax
 import jax.numpy as jnp
@@ -9,7 +10,7 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.core.engine import ArcaneEngine
 from repro.models.transformer import LM
-from repro.serving.engine import ServeSession
+from repro.serving.engine import ServeSession, decode_inplace_share
 
 ENGINE = ArcaneEngine(backend="ref")
 
@@ -74,6 +75,61 @@ def test_ragged_lengths_isolated(rng):
         return r.out_tokens
 
     assert run_with(other1) == run_with(other2)
+
+
+# ------------------------------------------------------- in-place decode
+def test_step_donates_the_previous_cache(rng):
+    cfg = get_smoke_config("stablelm-3b")
+    model = LM(cfg, ENGINE)
+    sess = ServeSession(model, model.init_params(jax.random.key(0)),
+                        max_slots=2, max_len=64)
+    sess.submit(rng.integers(0, cfg.vocab, 5), max_new_tokens=4)
+    sess.step()
+    before = jax.tree.leaves(sess.cache)
+    sess.step()
+    assert all(leaf.is_deleted() for leaf in before)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(sess.cache))
+
+
+@pytest.mark.parametrize("arch,share", [
+    ("stablelm-3b", 1.0), ("minicpm3-4b", 0.0),
+    ("jamba-1.5-large-398b", None), ("whisper-large-v3", None)])
+def test_decode_inplace_share(arch, share):
+    """1 where every leaf is self-attention K/V, 0 where none is, strictly
+    between for mamba beside attention and for the cross-attention K/V."""
+    model = LM(get_smoke_config(arch), ENGINE)
+    if model.cfg.enc_dec:       # a session has no encoder: shapes alone
+        got = decode_inplace_share(model,
+                                   model.cache_shapes(2, 64, enc_len=16))
+    else:
+        got = ServeSession(model, None, max_slots=2,
+                           max_len=64).decode_inplace_share
+    if share is None:
+        assert 0.0 < got < 1.0
+    else:
+        assert got == share
+
+
+def test_decode_step_writes_no_whole_layer():
+    """The served decode step, lowered on the kernel path, neither slices
+    nor updates a whole layer's K or V: only single positions go in, and
+    the kernel reads the layer where it lies in the stack."""
+    cfg = get_smoke_config("stablelm-3b")
+    model = LM(cfg, ArcaneEngine(backend="pallas"))
+    sess = ServeSession(model, model.param_shapes(), max_slots=2, max_len=64)
+    k = sess.cache[0]["k"]
+    assert k.shape[0] == 2                     # two layers in the stack
+    layer = "x".join(map(str, k.shape[1:]))
+    dtype = {"bfloat16": "bf16", "float32": "f32"}[str(k.dtype)]
+    whole = {f"tensor<{layer}x{dtype}>", f"tensor<1x{layer}x{dtype}>"}
+    ints = jax.ShapeDtypeStruct((2,), jnp.int32)
+    text = sess._decode.lower(model.param_shapes(), ints, ints,
+                              sess.cache).as_text()
+    ops = [ln for ln in text.splitlines()
+           if re.search(r"stablehlo\.dynamic_(update_)?slice", ln)]
+    assert ops, "no dynamic slices at all: the parse is wrong"
+    for ln in ops:
+        assert not whole & set(re.findall(r"tensor<[^>]*>", ln)), ln
 
 
 # ---------------------------------------------------------------- spans
@@ -156,3 +212,9 @@ def test_profiler_leaves_tokens_unchanged(traced_session):
     assert lives_off == lives_on
     assert [r.out_tokens for r in reqs_off] == [r.out_tokens for r in reqs_on]
     assert all(len(r.out_tokens) == 4 for r in reqs_on)
+
+
+def test_decode_span_carries_inplace_share(traced_session):
+    _, _, spans = traced_session
+    decodes = [s for s in spans if s[0] == "serve.decode"]
+    assert decodes and all(s[3]["inplace_share"] == 1.0 for s in decodes)

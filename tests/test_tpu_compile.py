@@ -90,16 +90,27 @@ def test_decode_attention_compiles(one_chip, batch):
     assert "tpu_custom_call" in text
 
 
-def test_decode_step_compiles_at_batch_4(one_chip):
-    """Two full-width layers of the served decode step on the pallas engine."""
+def _decode_step_text(sharding, max_len: int) -> str:
+    """Two full-width layers of the served decode step on the pallas engine,
+    at batch 4, compiled."""
     cfg = dataclasses.replace(get_config("stablelm-3b"), n_layers=2)
     model = LM(cfg, ArcaneEngine(backend="pallas", interpret=False))
 
     def on_chip(tree):
-        return jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+        return jax.tree.map(lambda s: _spec(sharding, s.shape, s.dtype), tree)
 
-    text = _compiled_text(
+    return _compiled_text(
         model.decode_step, on_chip(model.param_shapes()),
-        _spec(one_chip, (4,), jnp.int32), _spec(one_chip, (4,), jnp.int32),
-        on_chip(model.cache_shapes(4, MAX_LEN)))
-    assert "tpu_custom_call" in text
+        _spec(sharding, (4,), jnp.int32), _spec(sharding, (4,), jnp.int32),
+        on_chip(model.cache_shapes(4, max_len)))
+
+
+def test_decode_step_compiles_at_batch_4(one_chip):
+    assert "tpu_custom_call" in _decode_step_text(one_chip, MAX_LEN)
+
+
+def test_decode_step_compiles_at_unaligned_max_len(one_chip):
+    """A max_len that 128 does not divide: the K/V write and the decode
+    kernel still move 128-lane blocks (the last one runs past the cache),
+    never a whole layer, so the step fits the chip's scoped VMEM."""
+    assert "tpu_custom_call" in _decode_step_text(one_chip, 1000)
