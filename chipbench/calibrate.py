@@ -6,9 +6,10 @@
 
 For each seed: a full run of the cell (weights, traffic and window from
 that seed, the compiled programs shared), judged as the benchmark judges
-it, and the int8 control put in the program's place on the same sample,
-judged by the same comparison. The limit goes between the largest program
-reading and the smallest control reading. ``--fault`` plants one of
+it, and the int8 control (the cell's own reference, ``logits_at`` of its
+``spec.Equations`` with ``quant``) put in the program's place on the same
+sample, judged by the same comparison. The limit goes between the largest
+program reading and the smallest control reading. ``--fault`` plants one of
 ``cbench.faults`` in the program first, at the cell's own size;
 ``--rate`` offers an open-loop cell another rate. One JSON line per seed;
 needs the chip, like ``run.py``.

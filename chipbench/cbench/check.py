@@ -1,14 +1,17 @@
 """The output check that decides ``correct``.
 
 Once the window has closed and the session is freed, a sample of the
-finished requests is run through the float32 reference
-(``cbench.reference``) over its prompt and its served tokens. The sample
-holds the longest finished request, one drawn from the seed among those
-each slot served (so a fault in one slot of the batch is seen), and
-further draws up to the cell's ``sample``. For every served token the
-reference gives the gap by which that token's logit lies below the
-reference's best at that position (0 where the served token is the
-reference's own greedy choice). The mean gap is compared with the cell's
+finished requests is run through the configuration's float32 reference
+(its ``logits_at``, ``cbench.spec.Equations``) over its prompt and its
+served tokens. The sample holds the longest finished request, one drawn
+from the seed among those each slot served (so a fault in one slot of the
+batch is seen), and further draws up to the cell's ``sample``. For every
+served token the reference gives the gap by which that token's logit lies
+below the reference's best at that position (0 where the served token is the
+reference's own greedy choice). Positions at which the reference's answer
+hangs on a discrete choice within the configuration's rounding (its
+``settled_at``; a router's top-k) are left out, and the count of tokens
+checked is the count of the rest. The mean gap is compared with the cell's
 limit. Served tokens are greedy choices of the program's bfloat16 logits,
 so a sound run's gaps stay at the size of bfloat16 rounding among
 near-ties; a wrong layer, cache row or position moves them by the logits'
@@ -17,15 +20,14 @@ own scale.
 The first served token comes from the prefill and the rest from decode
 steps through the cache, so one sample covers both paths.
 
-The control is the reference in int8 (W8A8) put in the program's place:
-at the same positions its greedy picks are judged by the same comparison,
-and have to come out not correct.
+The control is the same reference in int8 (W8A8) put in the program's
+place: at the same positions its greedy picks are judged by the same
+comparison, and have to come out not correct.
 """
 from __future__ import annotations
 
 import numpy as np
 
-from cbench import reference
 from cbench.traffic import rng_for
 
 
@@ -66,22 +68,24 @@ def _rows(prompt, toks, seq_len: int, n_rows: int):
     return seq, rows, tgt
 
 
-def gaps(model: dict, params, prompt, toks, *, seq_len: int, n_rows: int,
-         control: bool = False) -> list[np.ndarray]:
-    """Per served token, the reference's best logit minus its logit of the
-    served token; with ``control`` also, at the same positions, the gap of
-    the int8 control's greedy choice (the sequence still holds the
-    program's tokens)."""
+def gaps(eq, model: dict, params, prompt, toks, *, seq_len: int,
+         n_rows: int, control: bool = False) -> list[np.ndarray]:
+    """Per served token at a settled row (``eq.settled_at``), the
+    reference's best logit minus its logit of the served token; with
+    ``control`` also, at the same rows, the gap of the int8 control's
+    greedy choice (the sequence still holds the program's tokens). ``eq``
+    is the configuration's ``cbench.spec.Equations``."""
     import jax.numpy as jnp
     seq, rows, tgt = _rows(np.asarray(prompt), np.asarray(toks), seq_len, n_rows)
-    ref = reference.logits_at(model, params, seq, rows)
+    ref = eq.logits_at(model, params, seq, rows)
     picks = [jnp.asarray(tgt)]
     if control:
-        picks.append(jnp.argmax(reference.logits_at(model, params, seq, rows,
-                                                     quant=True), -1))
+        picks.append(jnp.argmax(eq.logits_at(model, params, seq, rows, quant=True),
+                                -1))
+    keep = np.asarray(eq.settled_at(model, params, seq, rows), bool)[: len(toks)]
     best = ref.max(-1)
     return [np.asarray(best - jnp.take_along_axis(ref, p[:, None], -1)[:, 0],
-                       np.float64)[: len(toks)] for p in picks]
+                       np.float64)[: len(toks)][keep] for p in picks]
 
 
 def judge(g: np.ndarray, limits: dict) -> tuple[bool, dict]:
@@ -102,14 +106,16 @@ def judge(g: np.ndarray, limits: dict) -> tuple[bool, dict]:
     return ok, numbers
 
 
-def run_check(model: dict, params, reqs: list, seed: int, mix: dict,
+def run_check(eq, model: dict, params, reqs: list, seed: int, mix: dict,
               limits: dict, *, control: bool = False) -> list[tuple[bool, dict]]:
     """``[judge(program's gaps)]``, and with ``control`` a second entry,
-    ``judge(control's gaps)`` on the same sample."""
+    ``judge(control's gaps)`` on the same sample, both against the
+    reference of the equations ``eq``."""
     picked = sample(reqs, seed, limits["sample"])
     n_rows = -(-max(_max_out(mix), 1) // 128) * 128
     kw = dict(seq_len=mix["max_len"], n_rows=n_rows, control=control)
-    per_req = [gaps(model, params, r.draw.prompt, list(r.handle.out_tokens), **kw)
+    per_req = [gaps(eq, model, params, r.draw.prompt,
+                    list(r.handle.out_tokens), **kw)
                for r in picked]
     cols = zip(*per_req) if per_req else [[]] * (1 + control)
     return [judge(np.concatenate(c) if c else np.zeros(0), limits) for c in cols]

@@ -6,6 +6,7 @@ from typing import Optional
 
 from cbench import counts
 from cbench.peaks import ChipPeaks
+from cbench.spec import Equations, default_equations
 from cbench.tracing import Trace
 
 
@@ -21,6 +22,8 @@ class Context:
     compiles_in_window: int
     peaks: Optional[ChipPeaks]    # None off a known chip
     trace: Optional[Trace] = None
+    equations: Equations = dataclasses.field(      # the cell's (``spec.Cell``)
+        default_factory=default_equations)
 
 
 def window_steps(ctx) -> list:
@@ -62,9 +65,10 @@ def tokens_in_window(ctx) -> int:
     return sum(1 for r in ctx.reqs for t in r.times if t0 < t <= t1)
 
 
-def step_model_flops(m, step) -> int:
-    return (sum(counts.model_flops_prefill(m, s) for s in step.prefill_lens)
-            + sum(counts.model_flops_decode(m, n) for n in step.decode_lens))
+def step_model_flops(ctx, step) -> int:
+    eq, m = ctx.equations, ctx.model
+    return (sum(eq.model_flops_prefill(m, s) for s in step.prefill_lens)
+            + sum(eq.model_flops_decode(m, n) for n in step.decode_lens))
 
 
 def kernel_roofline(ctx, op_pattern: str, calls_of_step) -> Optional[float]:
@@ -82,12 +86,12 @@ def kernel_roofline(ctx, op_pattern: str, calls_of_step) -> Optional[float]:
 def gemm_calls_of_step(ctx):
     """GEMM calls of a step: the decode at the session's full batch (it
     decodes every slot) and a batch-1 prefill per admitted prompt."""
-    m, b = ctx.model, ctx.mix["max_slots"]
+    m, b, gemm_calls = ctx.model, ctx.mix["max_slots"], ctx.equations.gemm_calls
 
     def calls(st):
-        out = [c for s in st.prefill_lens for c in counts.gemm_calls(m, s, 1)]
+        out = [c for s in st.prefill_lens for c in gemm_calls(m, s, 1)]
         if st.decode_lens:
-            out += counts.gemm_calls(m, b, b)
+            out += gemm_calls(m, b, b)
         return out
     return calls
 

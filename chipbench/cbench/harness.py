@@ -5,11 +5,14 @@ on a TPU and the CPU tests call it at smoke sizes.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import shutil
 import sys
 import tempfile
 import time
+import types
+import typing
 
 import jax
 
@@ -22,13 +25,28 @@ TRACE_S = 3.0         # and lasts this long, at most 40% of the window
 
 
 def program_config(m: dict):
-    """The program's ``ModelConfig`` from a configuration's ``model`` block."""
-    from repro.configs.base import LayerSpec, MLAConfig, ModelConfig
-    kw = dict(m)
-    kw["pattern"] = tuple(LayerSpec(**p) for p in m["pattern"])
-    if m.get("mla"):
-        kw["mla"] = MLAConfig(**m["mla"])
-    return ModelConfig(**kw)
+    """The program's ``ModelConfig`` from a configuration's ``model`` block.
+    Each field is built as ``ModelConfig``'s own annotation says, so a
+    sub-configuration (``moe``, ``mla``, a tuple of ``LayerSpec``) arrives
+    as its dataclass, whichever the program has."""
+    from repro.configs.base import ModelConfig
+    return _typed(ModelConfig, m)
+
+
+def _typed(tp, v):
+    """``v`` (parsed JSON) as the annotated type ``tp``: dataclasses from
+    dicts, tuples from lists, through ``Optional``."""
+    if v is None:
+        return None
+    if dataclasses.is_dataclass(tp):
+        hints = typing.get_type_hints(tp)
+        return tp(**{k: _typed(hints[k], x) for k, x in v.items()})
+    origin, args = typing.get_origin(tp), typing.get_args(tp)
+    if origin in (typing.Union, types.UnionType):
+        return _typed(next(a for a in args if a is not type(None)), v)
+    if origin is tuple:
+        return tuple(_typed(args[0], x) for x in v)
+    return v
 
 
 def build(cell: spec.Cell, seed: int):
@@ -102,8 +120,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     del session
     gc.collect()
     t_check = time.perf_counter()
-    (correct, numbers), *ctl = check.run_check(m, params, lp.reqs, seed, mix,
-                                               cell.limits, control=control)
+    (correct, numbers), *ctl = check.run_check(
+        cell.equations, m, params, lp.reqs, seed, mix, cell.limits,
+        control=control)
     print(f"chipbench: check {time.perf_counter() - t_check:.3f} s",
           file=sys.stderr, flush=True)
 
@@ -113,7 +132,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         peaks = None
     ctx = derive.Context(model=m, mix=mix, reqs=lp.reqs, steps=lp.steps,
                          window=lp.window, setup_s=setup_s,
-                         compiles_in_window=in_window, peaks=peaks, trace=tr)
+                         compiles_in_window=in_window, peaks=peaks, trace=tr,
+                         equations=cell.equations)
     metrics = {}
     for entry in (cell.per_layer if trace else cell.end_to_end):
         value = spec.reader(cell.root, entry["name"])(ctx)

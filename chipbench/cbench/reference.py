@@ -23,6 +23,11 @@ Equations (``x`` the residual stream, one sequence, positions 0..S-1):
 * FFN: ``h = norm2(x)``; ``x += (silu(h Wg) * (h Wu)) Wd``;
 * final norm, then ``logits = x table_out^T``.
 
+A configuration with other layers brings its own equations
+(``chipbench/equations/<config>.py``), which may reuse these parts and
+pass ``logits_at`` a ``block`` of its own, and its own ``settled_at``
+where its reference makes a discrete choice (a router's top-k).
+
 Norms: ``layernorm`` is ``(x - mean)/sqrt(var + 1e-5) * scale + bias``;
 ``rmsnorm`` is ``x/sqrt(mean(x^2) + 1e-6) * (1 + scale)``.
 
@@ -35,10 +40,12 @@ faster path would be tempted to take.
 from __future__ import annotations
 
 import functools
+import json
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 HI = jax.lax.Precision.HIGHEST
 Q_CHUNK = 256          # query rows per attention block
@@ -143,18 +150,28 @@ def _mla_layer(m, p, h, quant):
     return _mm(o.reshape(s, -1), p["o"]["w"], quant)
 
 
-def _block(m, kind, p, x, quant):
-    h = _norm(m["norm"], p["ln1"], x)
+def _attention(m, kind, p, h, quant):
+    """The attention sublayer of a layer of ``kind``, without its residual."""
     if kind == "attn":
-        x = x + _attn_layer(m, p["attn"], h, quant)
-    elif kind == "mla":
-        x = x + _mla_layer(m, p["attn"], h, quant)
-    else:
-        raise ValueError(f"reference has no layer kind {kind!r}")
-    h = _norm(m["norm"], p["ln2"], x)
-    f = p["ffn"]
+        return _attn_layer(m, p, h, quant)
+    if kind == "mla":
+        return _mla_layer(m, p, h, quant)
+    raise ValueError(f"reference has no layer kind {kind!r}")
+
+
+def _swiglu(f, h, quant):
+    """The dense FFN, without its residual."""
     act = jax.nn.silu(_mm(h, f["gate"]["w"], quant)) * _mm(h, f["up"]["w"], quant)
-    return x + _mm(act, f["down"]["w"], quant)
+    return _mm(act, f["down"]["w"], quant)
+
+
+def _block(m, spec, p, x, quant):
+    """One layer; ``spec`` is its entry of ``m["pattern"]``."""
+    if spec.get("moe"):
+        raise ValueError("reference models dense FFNs only")
+    h = _norm(m["norm"], p["ln1"], x)
+    x = x + _attention(m, spec["kind"], p["attn"], h, quant)
+    return x + _swiglu(p["ffn"], _norm(m["norm"], p["ln2"], x), quant)
 
 
 def check_supported(m: dict) -> None:
@@ -166,18 +183,17 @@ def check_supported(m: dict) -> None:
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(key: tuple, quant: bool):
-    m = dict(key)
-    m["mla"] = dict(m["mla"]) if m.get("mla") else None
-    kinds = [k for k in m["pattern"]]
+def _programs(key: str, quant: bool, block):
+    m = json.loads(key)
+    specs = m["pattern"]
 
     def hidden(params, tokens):
         """tokens (S,) -> final-normed hidden states (S, d), float32."""
         x = params["embed"]["table"][tokens].astype(jnp.float32)
 
         def period(x, bps):
-            for kind, bp in zip(kinds, bps):
-                x = _block(m, kind, bp, x, quant)
+            for spec, bp in zip(specs, bps):
+                x = block(m, spec, bp, x, quant)
             return x, None
 
         x, _ = jax.lax.scan(period, x, params["blocks"])
@@ -190,18 +206,27 @@ def _programs(key: tuple, quant: bool):
     return jax.jit(hidden), jax.jit(logits)
 
 
-def _freeze(m: dict) -> tuple:
-    out = dict(m)
-    out["pattern"] = tuple(p["kind"] for p in m["pattern"])
-    out["mla"] = tuple(sorted(m["mla"].items())) if m.get("mla") else None
-    return tuple(sorted(out.items()))
+def _freeze(m: dict) -> str:
+    """A hashable key that holds the whole model block, nested dicts and
+    lists included."""
+    return json.dumps(m, sort_keys=True)
 
 
-def logits_at(model: dict, params, tokens, rows, *, quant: bool = False):
+def logits_at(model: dict, params, tokens, rows, *, quant: bool = False,
+              block=None):
     """Logits (len(rows), V) at positions ``rows`` of the sequence
     ``tokens``, already padded to a multiple of ``Q_CHUNK``: padding sits
-    at the end, where causality keeps it from every earlier position."""
+    at the end, where causality keeps it from every earlier position.
+    ``block(m, spec, params, x, quant)`` computes one layer (``_block``
+    where none is given)."""
     check_supported(model)
-    hidden, logits = _programs(_freeze(model), quant)
+    hidden, logits = _programs(_freeze(model), quant, block or _block)
     h = hidden(params, jnp.asarray(tokens, jnp.int32))
     return logits(params, h[jnp.asarray(rows, jnp.int32)])
+
+
+def settled_at(model: dict, params, tokens, rows):
+    """Every row: these layers make no discrete choice on the way to the
+    logits, so rounding cannot swap one (an equations module whose layers
+    route tokens marks the rows where it could)."""
+    return np.ones(len(rows), bool)

@@ -1,6 +1,6 @@
 """GEMM kernel: sum over its calls in the traced steps of
 max(FLOPs / peak, bytes / HBM bandwidth), over the device time of its
-events, in percent. Calls from ``cbench.counts.gemm_calls``."""
+events, in percent. Calls from the cell's ``gemm_calls``."""
 from cbench import derive
 from cbench.programs import GEMM
 

@@ -8,6 +8,6 @@ from cbench import derive
 def read(ctx):
     if ctx.trace is None or ctx.peaks is None:
         return None
-    flops = sum(derive.step_model_flops(ctx.model, s)
+    flops = sum(derive.step_model_flops(ctx, s)
                 for s in derive.traced_steps(ctx))
     return 100.0 * flops / (ctx.trace.window_s * ctx.peaks.bf16_flops)

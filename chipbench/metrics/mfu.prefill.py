@@ -1,6 +1,6 @@
 """Model FLOPs of the prompts prefilled in the traced steps over the
 prefill programs' device time times the chip's bf16 peak, in percent."""
-from cbench import counts, derive
+from cbench import derive
 from cbench.programs import PREFILL
 
 
@@ -11,5 +11,5 @@ def read(ctx):
     spent = sum(ctx.trace.module_runs(PREFILL))
     if not lens or spent <= 0:
         return None
-    flops = sum(counts.model_flops_prefill(ctx.model, s) for s in lens)
+    flops = sum(ctx.equations.model_flops_prefill(ctx.model, s) for s in lens)
     return 100.0 * flops / (spent * ctx.peaks.bf16_flops)

@@ -50,7 +50,7 @@ def main(argv=None) -> int:
         t0, t1 = lp.run(args.seconds)
         ctx = derive.Context(model=m, mix=mix, reqs=lp.reqs, steps=lp.steps,
                              window=(t0, t1), setup_s=0.0, compiles_in_window=0,
-                             peaks=None)
+                             peaks=None, equations=cell.equations)
         ttft = derive.ttfts_s(ctx)
         done = sum(1 for r in lp.reqs if r.handle.done and r.times[-1] <= t1)
         print(json.dumps({

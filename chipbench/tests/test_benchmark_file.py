@@ -4,7 +4,7 @@ import re
 
 import pytest
 
-from cbench import spec
+from cbench import counts, reference, spec
 from conftest import BENCH, ROOT
 
 B = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -51,3 +51,26 @@ def test_traffic_names_its_source():
     for w in B["workloads"]:
         mix = spec.load(ROOT, w["name"]).mix
         assert mix["source"] and "\n" not in mix["source"]
+
+
+def test_equations_files_name_configs():
+    """``chipbench/equations/<config>.py`` belongs to a configuration of
+    ``BENCHMARK.json`` and defines the whole interface."""
+    configs = {c["name"] for c in B["configs"]}
+    for path in sorted((BENCH / "equations").glob("*.py")):
+        assert path.stem in configs
+        spec.equations(ROOT, path.stem)       # raises where a function is missing
+
+
+@pytest.mark.parametrize("config", ["stablelm-3b", "minicpm3-4b"])
+def test_default_equations_are_the_yardstick(config):
+    eq = spec.equations(ROOT, config)
+    assert eq == spec.default_equations()
+    assert eq.logits_at is reference.logits_at
+    assert eq.settled_at is reference.settled_at
+    for name in ("gemm_calls", "decode_attention_calls", "flash_attention_calls",
+                 "model_flops_decode", "model_flops_prefill"):
+        assert getattr(eq, name) is getattr(counts, name)
+    for w in B["workloads"]:
+        if w["config"] == config:
+            assert spec.load(ROOT, w["name"]).equations == eq

@@ -36,24 +36,8 @@ def test_int8_control_fails(kind):
     finished request of 240 served tokens, is judged not correct by the
     check's own comparison: its picks' mean gap below the float32
     reference's best exceeds the limit."""
-    from conftest import SMOKE_MODELS
-    from cbench import check, weights
-    from cbench.harness import program_config
-    from cbench.loop import Req
-    from cbench.traffic import Draw
-    from repro.core.engine import ArcaneEngine
-    m = SMOKE_MODELS[kind]
-    shapes = LM(program_config(m), ArcaneEngine("ref")).param_shapes()
-    params = weights.make_params(shapes, 1)
-    rng = np.random.default_rng(1)
-    prompt = rng.integers(0, 256, 16).astype(np.int32)
-    handle = SimpleNamespace(uid=0, done=True,
-                             out_tokens=rng.integers(0, 256, 240).tolist())
-    reqs = [Req(Draw(prompt, 240, 0.0), handle, 0.0, 0.0, slot=0)]
-    mix = {"max_len": 256, "output_len": {"uniform": [240, 240]}}
-    limits = {"token_gap_mean": LIMIT, "sample": 1, "min_tokens": 100}
-    _, (ctl_ok, ctl) = check.run_check(m, params, reqs, SEED, mix, limits,
-                                       control=True)
+    from conftest import SMOKE_MODELS, int8_control
+    ctl_ok, ctl = int8_control(SMOKE_MODELS[kind], spec.default_equations(), LIMIT)
     assert ctl_ok is False
     assert ctl["token_gap_mean"][0] > LIMIT and ctl["tokens_checked"][0] == 240
 
@@ -81,3 +65,29 @@ def test_sample_covers_every_slot():
     assert all(r.handle.done for r in got)
     assert len({id(r) for r in got}) == 6
     assert len(check.sample(reqs, SEED, 2)) == 4     # every slot, even over k
+
+
+def test_gaps_leave_out_unsettled_rows():
+    """Rows that the equations' ``settled_at`` marks are left out of the
+    program's gaps and the control's alike, and so out of the count."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from cbench import check
+
+    def logits_at(model, params, tokens, rows, *, quant=False):
+        out = np.zeros((len(rows), 8), np.float32)
+        out[:, 0] = 1.0                  # the reference's best: token 0
+        out[:, 1] = 2.0 if quant else 0.0   # the control's: token 1
+        return jnp.asarray(out)
+
+    eq = dataclasses.replace(
+        spec.default_equations(), logits_at=logits_at,
+        settled_at=lambda model, params, tokens, rows: np.arange(len(rows)) % 2 == 0)
+    prog, ctl = check.gaps(eq, {}, None, [5, 6], [0, 3, 0, 3, 3], seq_len=16,
+                           n_rows=8, control=True)
+    assert prog.tolist() == [0.0, 0.0, 1.0]      # rows 0, 2, 4 of gaps 0 1 0 1 1
+    assert ctl.tolist() == [1.0, 1.0, 1.0]
+    assert check.judge(prog, {"token_gap_mean": 0.5, "min_tokens": 3})[1][
+        "tokens_checked"] == [3, 3]

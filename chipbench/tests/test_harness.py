@@ -1,20 +1,28 @@
 """The harness end to end on the CPU, at smoke sizes with interpret-mode
-kernels: a closed and an open loop, cells added from files alone, the
-censored TTFT tail, and the command's refusal of anything but a TPU."""
+kernels: a closed and an open loop, cells added from files alone (an MoE
+configuration with its own equations among them), the censored TTFT tail,
+and the command's refusal of anything but a TPU."""
+import dataclasses
 import json
 import os
+import shutil
 import subprocess
 import sys
 import time
 
+import numpy as np
 import pytest
 
-from cbench import derive, spec
-from cbench.harness import run_cell
+from cbench import counts, derive, faults, peaks, reference, spec, tracing, weights
+from cbench.counts import gemm_cost
+from cbench.harness import program_config, run_cell
 from cbench.loop import Req, Step
 from cbench.traffic import Draw, Stream
+from repro.configs import ARCHS, get_config, get_smoke_config
+from repro.core.engine import ArcaneEngine
+from repro.models.transformer import LM
 
-from conftest import BENCH, ROOT
+from conftest import BENCH, EQUATIONS, ROOT, SMOKE_MODELS, int8_control
 
 SEED = 2**31 + 4242
 E2E = {"itl_p95_ms", "tokens_per_s", "setup_s"}
@@ -83,6 +91,131 @@ def test_per_layer_metric_from_new_file(smoke_root):
                          window=(1.0, 2.0), setup_s=0.0, compiles_in_window=0,
                          peaks=None)
     assert spec.reader(root, "smoke.steps")(ctx) == 2
+
+
+# The MoE cell's limit on the mean gap: over the same 600 served tokens a
+# seed (15 seeds), sound runs of the float32 smoke program read 0 and its
+# equations' int8 control 6.3e-4 to 4.4e-3. Over a run's own sample of ~30
+# tokens the control reads 6.5e-5 to 8.4e-3 (15 seeds): too few tokens to
+# judge it, so the control test judges 240.
+MOE_LIMIT = 2e-4
+
+
+def _moe_root(smoke_root):
+    """A cell whose configuration is the program's MoE FFN, brought as new
+    files only: its configuration, traffic, limits and equations."""
+    root = smoke_root(kind="moe", limit=MOE_LIMIT)
+    (root / "chipbench" / "equations").mkdir()
+    shutil.copy(EQUATIONS / "smoke.py", root / "chipbench" / "equations" / "smoke.py")
+    return root
+
+
+def test_default_reference_rejects_moe():
+    m = SMOKE_MODELS["moe"]
+    params = weights.make_params(
+        LM(program_config(m), ArcaneEngine("ref")).param_shapes(), 1)
+    with pytest.raises(ValueError, match="dense FFNs only"):
+        reference.logits_at(m, params, np.zeros(256, np.int32), np.zeros(128, np.int32))
+
+
+def test_moe_cell_from_new_files(smoke_root):
+    root = _moe_root(smoke_root)
+    cell = spec.load(root, "smoke.closed")
+    assert cell.equations.logits_at is not reference.logits_at
+    out = run_cell(cell, SEED, 2.0, False, time.perf_counter())
+    assert out["correct"] is True
+    assert out["check"]["token_gap_mean"]["value"] <= MOE_LIMIT
+    assert set(out["metrics"]) == E2E
+
+
+@pytest.mark.parametrize("fault", sorted(faults.FAULTS))
+def test_moe_cell_fault_fails(smoke_root, monkeypatch, fault):
+    monkeypatch.setattr(LM, "decode_step", faults.faulty_decode_step(fault))
+    out = _run(_moe_root(smoke_root), "smoke.closed")
+    assert out["correct"] is False
+    assert out["check"]["token_gap_mean"]["value"] > MOE_LIMIT
+
+
+def test_moe_int8_control_fails(smoke_root):
+    eq = spec.load(_moe_root(smoke_root), "smoke.closed").equations
+    ctl_ok, ctl = int8_control(SMOKE_MODELS["moe"], eq, MOE_LIMIT)
+    assert ctl_ok is False
+    assert ctl["token_gap_mean"][0] > MOE_LIMIT and ctl["tokens_checked"][0] == 240
+
+
+def test_moe_counts_through_the_cell_equations(smoke_root):
+    """The readers take the MoE cell's counts from its equations file: the
+    attention projections and the unembedding are GEMM kernel calls, the
+    router and the top_k experts' SwiGLU count in the model FLOPs only."""
+    cell = spec.load(_moe_root(smoke_root), "smoke.closed")
+    m, eq, p = cell.config["model"], cell.equations, peaks.chip_peaks("TPU v5 lite")
+    # smoke widths: d 64, 4 heads of 16 (4 kv heads), d_ff 128, vocab 256,
+    # 2 layers, 4 experts, top 2; one decode of 3 slots and one prefill of 16
+    # GEMMs: q, k, v, o of 2 layers at 3 and at 16 rows, two unembeddings
+    gemms = ([gemm_cost(r, 64, 64) for r in (3, 16) for _ in range(2 * 4)]
+             + [gemm_cost(3, 64, 256, 4), gemm_cost(1, 64, 256, 4)])
+    assert len(eq.gemm_calls(m, 3, 3)) == 2 * 4 + 1
+    per_token = 4 * 2 * 64 * 64 + 2 * 64 * 4 + 2 * 3 * 2 * 64 * 128
+    lengths = [20, 30, 40]
+    decode = sum(2 * (per_token + 4 * n * 4 * 16) + 2 * 64 * 256 for n in lengths)
+    prefill = 2 * 16 * per_token + 2 * 4 * 16 * 17 * (16 + 16) + 2 * 64 * 256
+    least = counts.least_seconds(gemms, p)
+    trace = tracing.Trace(
+        window=(0.0, 1.0), modules={0: [tracing.Ev("jit_prefill(7)", 0.1, 0.3)]},
+        ops={0: [tracing.Ev("gemm.1", 0.1, 0.1 + least), tracing.Ev("gemm.2", 0.4, 0.4 + least)]},
+        host=[tracing.Ev(tracing.WINDOW_SPAN, 0.0, 1.0)])
+    ctx = derive.Context(model=m, mix=cell.mix, reqs=[], window=(0.0, 1.0),
+                         steps=[Step(0.1, 0.5, 3, [16], lengths, traced=True)],
+                         setup_s=0.0, compiles_in_window=0, peaks=p, trace=trace,
+                         equations=eq)
+    read = {n: spec.reader(cell.root, n)(ctx)
+            for n in ("kernel.gemm.roofline", "mfu.decode", "mfu.prefill")}
+    assert read["kernel.gemm.roofline"] == pytest.approx(50.0)
+    assert read["mfu.decode"] == pytest.approx(100.0 * (decode + prefill) / p.bf16_flops)
+    assert read["mfu.prefill"] == pytest.approx(100.0 * prefill / (0.2 * p.bf16_flops))
+
+
+def test_moe_settled_rows_are_router_near_ties(smoke_root):
+    """The MoE equations leave out the rows where rounding at the compute
+    precision could swap a chosen expert: all of them where the router's
+    logits tie, a few in float32, more in bfloat16, which keeps none that
+    float32 leaves out."""
+    eq = spec.load(_moe_root(smoke_root), "smoke.closed").equations
+    m32 = SMOKE_MODELS["moe"]
+    m16 = dict(m32, compute_dtype="bfloat16")
+    params = weights.make_params(
+        LM(program_config(m32), ArcaneEngine("ref")).param_shapes(), 1)
+    tokens = np.random.default_rng(1).integers(0, 256, 256).astype(np.int32)
+    rows = np.arange(256)
+    s32, s16 = (eq.settled_at(m, params, tokens, rows) for m in (m32, m16))
+    assert s32.shape == s16.shape == (256,)
+    assert not (s16 & ~s32).any()
+    assert 0.5 < s16.mean() < s32.mean() and s32.mean() > 0.95
+    tied = dict(params, blocks=tuple(
+        dict(b, ffn=dict(b["ffn"], router={"w": b["ffn"]["router"]["w"] * 0}))
+        for b in params["blocks"]))
+    assert not eq.settled_at(m32, tied, tokens, rows).any()
+
+
+def test_equations_file_must_be_whole(smoke_root):
+    root = smoke_root()
+    (root / "chipbench" / "equations").mkdir()
+    (root / "chipbench" / "equations" / "smoke.py").write_text(
+        "from cbench.reference import logits_at  # noqa: F401\n")
+    with pytest.raises(AttributeError, match="gemm_calls"):
+        spec.load(root, "smoke.closed")
+
+
+@pytest.mark.parametrize("make", [get_config, get_smoke_config],
+                         ids=["config", "smoke"])
+@pytest.mark.parametrize("arch", sorted(ARCHS))
+def test_program_config_round_trips(arch, make):
+    """Every sub-configuration (moe, mla, mamba, rwkv, the pattern's
+    ``LayerSpec``s) comes back from its JSON form as the program's own."""
+    c = make(arch)
+    m = json.loads(json.dumps(dataclasses.asdict(c)))
+    assert program_config(dataclasses.asdict(c)) == c
+    assert program_config(m) == c
 
 
 def test_command_refuses_the_cpu():
