@@ -19,6 +19,7 @@ ARCHS: dict[str, str] = {
     "internvl2-1b": "internvl2_1b",
     "jamba-1.5-large-398b": "jamba_1_5_large_398b",
     "rwkv6-1.6b": "rwkv6_1_6b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

@@ -119,6 +119,17 @@ class ArcaneEngine:
                                interpret=self.interpret)
         return out.reshape(*lead, n)
 
+    def expert_gemm(self, x: jax.Array, w: jax.Array,
+                    group_sizes: jax.Array, *, block_m: int) -> jax.Array:
+        """xmk0 per expert over expert-sorted rows: (R, k) rows, group e's
+        starting on a multiple of ``block_m``, times w (G, k, n) → (R, n).
+        Logged as one GEMM over every row (the rows used are data)."""
+        r, k = x.shape
+        self._log(0, x.dtype, (x.shape, w.shape), 2 * r * k * w.shape[-1])
+        return kernels.expert_gemm(x, w, group_sizes, block_m=block_m,
+                                   backend=self.backend,
+                                   interpret=self.interpret)
+
     def leakyrelu(self, x: jax.Array, *, negative_slope: float = 0.01) -> jax.Array:
         self._log(1, x.dtype, (x.shape,), int(x.size))
         if self.backend == "ref":

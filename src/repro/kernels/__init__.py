@@ -10,6 +10,7 @@ from repro.kernels.leakyrelu.ops import leakyrelu
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.kv_write.ops import kv_write
+from repro.kernels.expert_gemm.ops import expert_gemm
 
 __all__ = ["gemm", "conv_layer", "maxpool", "leakyrelu", "flash_attention",
-           "decode_attention", "kv_write"]
+           "decode_attention", "kv_write", "expert_gemm"]

@@ -118,7 +118,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *,
     if cfg_overrides:
         cfg = _dc.replace(cfg, **cfg_overrides)
     if n_periods is not None:
-        repl = {"n_layers": n_periods * cfg.period}
+        repl = {"n_layers": cfg.n_dense_layers + n_periods * cfg.period}
         if cfg.enc_dec:
             repl["n_enc_layers"] = n_periods
         cfg = _dc.replace(cfg, **repl)

@@ -201,6 +201,6 @@ def moe_burst_from_config(arch: str, *, scale: int = 64, tokens: int = 4,
         raise ProgramError(f"{arch} has no MoE block to lower")
     spec = MoESpec(
         name=f"moe-{arch}", d=_scaled(cfg.d_model, scale),
-        ff=_scaled(cfg.d_ff, scale), tokens=tokens,
+        ff=_scaled(cfg.expert_ff, scale), tokens=tokens,
         experts=experts or cfg.moe.top_k, width=width, seed=seed)
     return lower_moe_burst(spec, **lower_kw), spec

@@ -56,9 +56,13 @@ def block_init(key, cfg: ModelConfig, spec: LayerSpec, *,
     return p
 
 
-def _ffn_apply(engine, params, cfg, spec, x):
+def _ffn_apply(engine, params, cfg, spec, x, *, dropless: bool = False):
+    """The FFN and its aux output; an MoE one dispatches dropless when
+    serving (prefill and decode; aux: the rows each held expert received)
+    and with capacity in the training forward (aux: the balance loss;
+    ``models.moe``)."""
     if spec.moe:
-        return moe(engine, params["ffn"], cfg, x)
+        return moe(engine, params["ffn"], cfg, x, dropless=dropless)
     return mlp(engine, params["ffn"], cfg, x), jnp.float32(0.0)
 
 
@@ -134,8 +138,10 @@ def init_block_cache(cfg: ModelConfig, spec: LayerSpec, batch: int,
 
 
 def block_prefill(engine, params, cfg, spec, x, positions, cache, *,
-                  enc_out=None):
-    """Prefill from position 0; returns (x, cache)."""
+                  enc_out=None, expert_rows: bool = False):
+    """Prefill from position 0; returns (x, cache), and with
+    ``expert_rows`` (x, cache, rows): an MoE block's rows per held expert
+    (n_held,) int32, None for any other block."""
     _, napply = _norm(cfg)
     h = napply(params["ln1"], x)
     if spec.kind in ("attn", "attn_local"):
@@ -168,7 +174,7 @@ def block_prefill(engine, params, cfg, spec, x, positions, cache, *,
         h2 = napply(params["ln2"], x)
         cm, cache["cm_x"] = rwkv_mod.rwkv_channel_mix(
             engine, params["mixer"], cfg, h2)
-        return x + cm, cache
+        return (x + cm, cache, None) if expert_rows else (x + cm, cache)
     x = x + h
     if enc_out is not None and "cross" in params:
         hc = napply(params["cross_ln"], x)
@@ -183,7 +189,9 @@ def block_prefill(engine, params, cfg, spec, x, positions, cache, *,
             engine, params["cross"], cfg, hc, positions, causal=False,
             kv_override=(kx, vx))
     h = napply(params["ln2"], x)
-    h, _ = _ffn_apply(engine, params, cfg, spec, h)
+    h, rows = _ffn_apply(engine, params, cfg, spec, h, dropless=True)
+    if expert_rows:
+        return x + h, cache, rows if spec.moe else None
     return x + h, cache
 
 
@@ -202,16 +210,18 @@ def decode_inplace_leaves(spec: LayerSpec) -> frozenset:
 
 
 def block_decode(engine, params, cfg, spec, x, position, cache, layer, *,
-                 enc_len: Optional[int] = None):
+                 enc_len: Optional[int] = None, expert_rows: bool = False):
     """One-token step. x: (B, d); cache: this pattern position's leaves for
     every period, stacked (n_periods, ...); layer: the period to run.
-    Returns (x, cache) with the stack updated at ``layer``."""
+    Returns (x, cache) with the stack updated at ``layer``; with
+    ``expert_rows`` (x, cache, rows) as ``block_prefill``."""
     inplace = decode_inplace_leaves(spec)
     one = {n: c if n in inplace
            else jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
            for n, c in cache.items()}
-    x, new = _block_decode_layer(engine, params, cfg, spec, x, position,
-                                 dict(one), layer, enc_len=enc_len)
+    x, new, rows = _block_decode_layer(engine, params, cfg, spec, x,
+                                       position, dict(one), layer,
+                                       enc_len=enc_len)
     out = {}
     for n, c in cache.items():
         if n in inplace:
@@ -220,12 +230,15 @@ def block_decode(engine, params, cfg, spec, x, position, cache, layer, *,
             out[n] = c
         else:
             out[n] = jax.lax.dynamic_update_index_in_dim(c, new[n], layer, 0)
+    if expert_rows:
+        return x, out, rows if spec.moe else None
     return x, out
 
 
 def _block_decode_layer(engine, params, cfg, spec, x, position, cache, layer,
                         *, enc_len: Optional[int] = None):
-    """One layer's decode; the in-place leaves are still the whole stack."""
+    """One layer's decode; the in-place leaves are still the whole stack.
+    Returns (x, cache, the FFN's aux output)."""
     _, napply = _norm(cfg)
     h = napply(params["ln1"], x)
     if spec.kind in ("attn", "attn_local"):
@@ -249,7 +262,7 @@ def _block_decode_layer(engine, params, cfg, spec, x, position, cache, layer,
         cm, cache["cm_x"] = rwkv_mod.rwkv_channel_mix(
             engine, params["mixer"], cfg, h2[:, None, :],
             cache["cm_x"])
-        return x + cm[:, 0], cache
+        return x + cm[:, 0], cache, None
     x = x + h
     if "cross" in params and "xk" in cache:
         hc = napply(params["cross_ln"], x)
@@ -264,5 +277,6 @@ def _block_decode_layer(engine, params, cfg, spec, x, position, cache, layer,
                        o.reshape(b, cfg.n_heads * cfg.resolved_head_dim))
         x = x + o
     h = napply(params["ln2"], x)
-    h, _ = _ffn_apply(engine, params, cfg, spec, h[:, None, :])
-    return x + h[:, 0], cache
+    h, rows = _ffn_apply(engine, params, cfg, spec, h[:, None, :],
+                         dropless=True)
+    return x + h[:, 0], cache, rows

@@ -1,5 +1,8 @@
 """Multi-head Latent Attention (MiniCPM3 / DeepSeek-style MLA).
 
+The query is projected directly (``q_lora_rank`` None, as DeepSeek-V2-Lite
+and Moonlight) or through its own low-rank latent (MiniCPM3).
+
 Train/prefill: latent KV is expanded to per-head K/V and the standard flash
 kernel runs. Decode: the **latent cache** is the near-memory operand — we use
 the absorbed-matmul identity
@@ -31,10 +34,14 @@ def mla_init(key, cfg: ModelConfig) -> dict:
     dt = cfg.pdtype
     qk_head = m.qk_nope_head_dim + m.qk_rope_head_dim
     keys = jax.random.split(key, 8)
+    if m.q_lora_rank is None:           # a direct q projection
+        q = {"q": dense_init(keys[0], d, h * qk_head, dt)}
+    else:
+        q = {"q_down": dense_init(keys[0], d, m.q_lora_rank, dt),
+             "q_norm": rmsnorm_init(m.q_lora_rank, dt),
+             "q_up": dense_init(keys[1], m.q_lora_rank, h * qk_head, dt)}
     return {
-        "q_down": dense_init(keys[0], d, m.q_lora_rank, dt),
-        "q_norm": rmsnorm_init(m.q_lora_rank, dt),
-        "q_up": dense_init(keys[1], m.q_lora_rank, h * qk_head, dt),
+        **q,
         "kv_down": dense_init(keys[2], d, m.kv_lora_rank + m.qk_rope_head_dim, dt),
         "kv_norm": rmsnorm_init(m.kv_lora_rank, dt),
         "k_up": truncated_normal_init(
@@ -54,8 +61,12 @@ def _project_qkv(engine, params, cfg, x, positions):
     b = x.shape[0]
     s = x.shape[1]
     qk_head = m.qk_nope_head_dim + m.qk_rope_head_dim
-    q_lat = rmsnorm(params["q_norm"], dense(engine, params["q_down"], x))
-    q = dense(engine, params["q_up"], q_lat).reshape(b, s, h, qk_head)
+    if "q" in params:
+        q = dense(engine, params["q"], x)
+    else:
+        q_lat = rmsnorm(params["q_norm"], dense(engine, params["q_down"], x))
+        q = dense(engine, params["q_up"], q_lat)
+    q = q.reshape(b, s, h, qk_head)
     q = q.transpose(0, 2, 1, 3)                                   # (B,H,S,qk)
     q_nope, q_rope = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
     kv = dense(engine, params["kv_down"], x)                      # (B,S,r+rope)

@@ -4,11 +4,14 @@ Depth is executed as ``lax.scan`` over *periods* of the repeating layer
 pattern (per-position parameter stacks with a leading ``n_periods`` axis), so
 HLO size is independent of layer count — essential for the 62/64/72-layer
 assigned configs — and the activation-checkpoint policy applies per period.
+Leading dense layers (``cfg.n_dense_layers``) are a stack and a scan of
+their own, run before the periods; their cache is the last entry of the
+cache tuple. A model without them has neither.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +35,12 @@ def _stack_init(key, n: int, init_fn):
 
 def _index_tree(tree: PyTree, i):
     return jax.tree.map(lambda x: x[i], tree)
+
+
+def _layer_rows(rows: tuple) -> jax.Array:
+    """Rows per held expert, one (n_periods, n_held) stack per MoE pattern
+    position → (n_moe_layers, n_held) in layer order."""
+    return jnp.stack(rows, axis=1).reshape(-1, rows[0].shape[-1])
 
 
 class LM:
@@ -77,6 +86,11 @@ class LM:
                                   cross=cross))
             for i, spec in enumerate(cfg.pattern)
         )
+        if cfg.n_dense_layers:
+            params["dense_blocks"] = _stack_init(
+                keys[4], cfg.n_dense_layers,
+                functools.partial(blk.block_init, cfg=cfg,
+                                  spec=cfg.dense_spec))
         if cfg.enc_dec:
             from repro.configs.base import LayerSpec
             enc_spec = LayerSpec(kind="attn")
@@ -137,6 +151,14 @@ class LM:
         positions = jnp.arange(s)
         enc_out = self._encoder(params, batch) if cfg.enc_dec else None
 
+        if cfg.n_dense_layers:
+            def dense_fn(h, bp):
+                h, _ = blk.block_forward(self.engine, bp, cfg, cfg.dense_spec,
+                                         h, positions)
+                return h, None
+            fn = jax.checkpoint(dense_fn) if self.remat else dense_fn
+            x, _ = self._scan(fn, x, params["dense_blocks"])
+
         def period_fn(carry, bps):
             h, aux = carry
             for i, spec in enumerate(cfg.pattern):
@@ -178,15 +200,17 @@ class LM:
         cfg = self.cfg
         dtype = dtype or cfg.cdtype
 
-        def one(spec):
-            # caches start at zero: allocate the (n_periods, ...) stack once
+        def one(spec, n):
+            # caches start at zero: allocate the (n, ...) stack once
             shapes = jax.eval_shape(lambda: blk.init_block_cache(
                 cfg, spec, batch, max_len, dtype, cross_len=enc_len))
             return jax.tree.map(
-                lambda x: jnp.zeros((cfg.n_periods, *x.shape), x.dtype),
-                shapes)
+                lambda x: jnp.zeros((n, *x.shape), x.dtype), shapes)
 
-        return tuple(one(spec) for spec in cfg.pattern)
+        caches = tuple(one(spec, cfg.n_periods) for spec in cfg.pattern)
+        if cfg.n_dense_layers:
+            caches += (one(cfg.dense_spec, cfg.n_dense_layers),)
+        return caches
 
     def cache_shapes(self, batch: int, max_len: int, *, dtype=None,
                      enc_len: int = 0):
@@ -194,40 +218,86 @@ class LM:
             lambda: self.init_cache(batch, max_len, dtype=dtype,
                                     enc_len=enc_len))
 
-    def prefill(self, params, batch, cache) -> tuple[jax.Array, tuple]:
-        """Process the full prompt; returns (last-position logits, cache)."""
+    def _wants_rows(self, expert_rows: Optional[list]) -> bool:
+        return (expert_rows is not None
+                and any(spec.moe for spec in self.cfg.pattern))
+
+    def prefill(self, params, batch, cache, *,
+                expert_rows: Optional[list] = None):
+        """Process the full prompt; returns (last-position logits, cache).
+
+        ``expert_rows``: a list to which a model with MoE layers appends
+        the rows each held expert received in each MoE layer,
+        (n_moe_layers, n_held) int32, a value of the calling trace for the
+        jitted caller to return (``train.step.make_serve_steps``)."""
         cfg = self.cfg
+        want = self._wants_rows(expert_rows)
         x = self._embed_inputs(params, batch)
         s = x.shape[1]
         positions = jnp.arange(s)
         enc_out = self._encoder(params, batch) if cfg.enc_dec else None
 
+        dense_cache = ()
+        if cfg.n_dense_layers:
+            def dense_fn(h, xs):
+                bp, c = xs
+                return blk.block_prefill(self.engine, bp, cfg, cfg.dense_spec,
+                                         h, positions, c)
+            x, c = self._scan(dense_fn, x, (params["dense_blocks"], cache[-1]))
+            cache, dense_cache = cache[:-1], (c,)
+
         def period_fn(h, xs):
             bps, caches = xs
-            new_caches = []
+            new_caches, rows = [], []
             for i, spec in enumerate(cfg.pattern):
-                h, c = blk.block_prefill(self.engine, bps[i], cfg, spec, h,
-                                         positions, caches[i],
-                                         enc_out=enc_out)
+                h, c, *r = blk.block_prefill(
+                    self.engine, bps[i], cfg, spec, h, positions, caches[i],
+                    enc_out=enc_out, expert_rows=want)
                 new_caches.append(c)
+                if want and spec.moe:
+                    rows.append(r[0])
+            if want:
+                return h, (tuple(new_caches), tuple(rows))
             return h, tuple(new_caches)
 
-        x, cache = self._scan(period_fn, x, (params["blocks"], cache))
+        x, ys = self._scan(period_fn, x, (params["blocks"], cache))
+        cache, rows = ys if want else (ys, ())
+        cache = cache + dense_cache
         _, napply = make_norm(cfg.norm)
         x = napply(params["final_norm"], x[:, -1:])
         table = params["unembed" if "unembed" in params else "embed"]
         logits = unembed(self.engine, table, x, softcap=cfg.final_softcap)
+        if want:
+            expert_rows.append(_layer_rows(rows))
         return logits[:, 0], cache
 
     def decode_step(self, params, tokens: jax.Array, position: jax.Array,
-                    cache: tuple, *, enc_len: int = 0):
-        """tokens: (B,) int32; position: (B,) → (logits (B, V), cache)."""
+                    cache: tuple, *, enc_len: int = 0,
+                    expert_rows: Optional[list] = None):
+        """tokens: (B,) int32; position: (B,) → (logits (B, V), cache);
+        ``expert_rows`` as in ``prefill``."""
         cfg = self.cfg
+        want = self._wants_rows(expert_rows)
         x = embed(params["embed"], tokens, scale=cfg.embed_scale)
         if cfg.enc_dec:
             from repro.models.layers import sinusoidal_at
             x = x + sinusoidal_at(position, cfg.d_model).astype(x.dtype)
         x = x.astype(cfg.cdtype)
+
+        dense_cache = ()
+        if cfg.n_dense_layers:
+            def dense_fn(carry, xs):
+                h, c = carry
+                bp, layer = xs
+                h, c = blk.block_decode(self.engine, bp, cfg, cfg.dense_spec,
+                                        h, position, c, layer,
+                                        enc_len=enc_len or None)
+                return (h, c), None
+            (x, c), _ = self._scan(
+                dense_fn, (x, cache[-1]),
+                (params["dense_blocks"],
+                 jnp.arange(cfg.n_dense_layers, dtype=jnp.int32)))
+            cache, dense_cache = cache[:-1], (c,)
 
         # The stacked cache rides in the carry, so each layer updates it in
         # place (see blocks.block_decode); only params and the layer index
@@ -235,19 +305,24 @@ class LM:
         def period_fn(carry, xs):
             h, caches = carry
             bps, layer = xs
-            new_caches = []
+            new_caches, rows = [], []
             for i, spec in enumerate(cfg.pattern):
-                h, c = blk.block_decode(self.engine, bps[i], cfg, spec, h,
-                                        position, caches[i], layer,
-                                        enc_len=enc_len or None)
+                h, c, *r = blk.block_decode(
+                    self.engine, bps[i], cfg, spec, h, position, caches[i],
+                    layer, enc_len=enc_len or None, expert_rows=want)
                 new_caches.append(c)
-            return (h, tuple(new_caches)), None
+                if want and spec.moe:
+                    rows.append(r[0])
+            return (h, tuple(new_caches)), (tuple(rows) if want else None)
 
-        (x, cache), _ = self._scan(
+        (x, cache), rows = self._scan(
             period_fn, (x, cache),
             (params["blocks"], jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+        cache = cache + dense_cache
         _, napply = make_norm(cfg.norm)
         x = napply(params["final_norm"], x)
         table = params["unembed" if "unembed" in params else "embed"]
         logits = unembed(self.engine, table, x, softcap=cfg.final_softcap)
+        if want:
+            expert_rows.append(_layer_rows(rows))
         return logits, cache

@@ -19,13 +19,20 @@ off, and change nothing that is jitted::
     │  ├─ serve.prefill              dispatch of the jitted prefill
     │  ├─ serve.insert               the copy into the batched cache
     │  └─ serve.first_token          sampling the first token
-    ├─ serve.decode  (inplace_share) dispatch of the jitted decode step
+    ├─ serve.decode  (inplace_share[, experts_held])
+    │                                dispatch of the jitted decode step
     ├─ serve.fetch                   logits to the host
     └─ serve.sample                  per-slot sampling and bookkeeping
 
 The admission spans carry the request's ``uid``. ``serve.decode`` carries
 ``inplace_share``: the share of the cache's bytes that the decode step
-updates in place (``decode_inplace_share``).
+updates in place (``decode_inplace_share``), and for an MoE model
+``experts_held``, the routed experts this device computes (``"0-7/64"``).
+
+An MoE model's prefill and decode programs also return the rows each held
+expert received in each MoE layer; every request keeps, per output token,
+that array of the program that produced it (``Request.expert_rows``), so
+a reader of the trace knows which experts' weights each program read.
 
 The decode step donates the batched cache: each step's cache is written in
 place, and the previous one is gone once ``step()`` returns.
@@ -60,12 +67,17 @@ class Request:
     # token was on the host: the stall one admission puts on every live slot
     t_admit: Optional[float] = None
     t_first: Optional[float] = None
+    # MoE models: per output token, the rows each held expert received in
+    # each MoE layer of the program that produced it, (n_moe_layers,
+    # n_held) int32; a decode step's one array is shared by its tokens
+    expert_rows: list = dataclasses.field(default_factory=list)
 
 
 def _insert_slot(batched: PyTree, one: PyTree, slot: int) -> PyTree:
     """Write a batch-1 cache pytree into slot ``slot`` of the batched cache.
 
-    Cache leaves are (n_periods, B, ...); the singleton cache has B = 1.
+    Cache leaves are (layers, B, ...), the layers of a pattern position's
+    stack or the leading dense layers'; the singleton cache has B = 1.
     """
     def put(c, n):
         return jax.lax.dynamic_update_slice(
@@ -78,7 +90,7 @@ def decode_inplace_share(model: LM, cache_shapes: tuple) -> float:
     from the shapes of a cache (``LM.cache_shapes``); the leaves that take
     the in-place path are those ``blocks.decode_inplace_leaves`` names."""
     total = inplace = 0
-    for spec, leaves in zip(model.cfg.pattern, cache_shapes):
+    for spec, leaves in zip(model.cfg.cache_specs, cache_shapes):
         names = decode_inplace_leaves(spec)
         for name, leaf in leaves.items():
             nbytes = leaf.size * leaf.dtype.itemsize
@@ -104,8 +116,17 @@ class ServeSession:
         self._key = jax.random.key(seed)
         self.decode_inplace_share = decode_inplace_share(
             model, model.cache_shapes(max_slots, max_len))
-        self._prefill1 = jax.jit(model.prefill)
-        _, decode_step = make_serve_steps(model)
+        self._decode_span = {"inplace_share": self.decode_inplace_share}
+        if model.cfg.moe is not None:
+            lo, hi = model.cfg.moe.held_range
+            self._decode_span["experts_held"] = (
+                f"{lo}-{hi - 1}/{model.cfg.moe.n_experts}")
+        prefill_step, decode_step = make_serve_steps(
+            model, expert_rows=any(spec.moe for spec in model.cfg.pattern))
+
+        def prefill(params, batch, cache):       # traced as jit_prefill
+            return prefill_step(params, batch, cache)
+        self._prefill1 = jax.jit(prefill)
         self._decode = jax.jit(decode_step, donate_argnums=(3,))
         self.pending: list[Request] = []
         self.finished: list[Request] = []
@@ -134,7 +155,7 @@ class ServeSession:
                 with TraceAnnotation("serve.init_cache", uid=req.uid):
                     one_cache = self.model.init_cache(1, self.max_len)
                 with TraceAnnotation("serve.prefill", uid=req.uid):
-                    logits, one_cache = self._prefill1(
+                    logits, one_cache, *rows = self._prefill1(
                         self.params, {"tokens": jnp.asarray(req.prompt[None])},
                         one_cache)
                 with TraceAnnotation("serve.insert", uid=req.uid):
@@ -143,6 +164,8 @@ class ServeSession:
                     tok = int(self._sample(logits, req.temperature)[0])
                 req.t_first = time.perf_counter()
                 req.out_tokens.append(tok)
+                if rows:
+                    req.expert_rows.append(np.asarray(rows[0]))
                 self.slots[slot] = req
                 self.positions[slot] = s
                 self.last_tokens[slot] = tok
@@ -162,20 +185,22 @@ class ServeSession:
             live = [i for i, r in enumerate(self.slots) if r is not None]
             if not live:
                 return 0
-            with TraceAnnotation("serve.decode",
-                                 inplace_share=self.decode_inplace_share):
+            with TraceAnnotation("serve.decode", **self._decode_span):
                 tokens = jnp.asarray(self.last_tokens)
                 positions = jnp.asarray(self.positions)
-                logits, self.cache = self._decode(self.params, tokens,
-                                                  positions, self.cache)
+                logits, self.cache, *rows = self._decode(
+                    self.params, tokens, positions, self.cache)
             with TraceAnnotation("serve.fetch"):
                 lg = np.asarray(logits, np.float32)
+                rows = np.asarray(rows[0]) if rows else None
             with TraceAnnotation("serve.sample"):
                 for slot in live:
                     req = self.slots[slot]
                     tok = self._sample(jnp.asarray(lg[slot : slot + 1]),
                                        req.temperature)[0]
                     req.out_tokens.append(int(tok))
+                    if rows is not None:
+                        req.expert_rows.append(rows)
                     self.positions[slot] += 1
                     self.last_tokens[slot] = int(tok)
                     hit_eos = (self.eos_id is not None

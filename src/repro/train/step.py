@@ -71,13 +71,21 @@ def make_train_step(model: LM, opt_cfg: AdamWConfig, *,
     return train_step
 
 
-def make_serve_steps(model: LM, *, enc_len: int = 0):
+def make_serve_steps(model: LM, *, enc_len: int = 0,
+                     expert_rows: bool = False):
+    """Prefill and decode steps, each → (logits, cache). With
+    ``expert_rows`` a model with MoE layers' steps also return the rows
+    each held expert received in each MoE layer (``LM.prefill``)."""
     def prefill_step(params, batch, cache):
-        return model.prefill(params, batch, cache)
+        rows = [] if expert_rows else None
+        logits, cache = model.prefill(params, batch, cache, expert_rows=rows)
+        return (logits, cache, *(rows or ()))
 
     def decode_step(params, tokens, position, cache):
-        return model.decode_step(params, tokens, position, cache,
-                                 enc_len=enc_len)
+        rows = [] if expert_rows else None
+        logits, cache = model.decode_step(params, tokens, position, cache,
+                                          enc_len=enc_len, expert_rows=rows)
+        return (logits, cache, *(rows or ()))
 
     return prefill_step, decode_step
 

@@ -6,7 +6,8 @@ from repro.configs import (ARCHS, SHAPES, get_config, get_smoke_config, grid,
 
 
 def test_ten_archs_registered():
-    assert len(ARCHS) == 10
+    """The ten assigned architectures, and Moonlight-16B-A3B beside them."""
+    assert len(ARCHS) == 11 and "moonlight-16b-a3b" in ARCHS
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
@@ -23,6 +24,7 @@ def test_exact_published_geometry(arch):
         "internvl2-1b": (24, 896, 14, 2, 4864, 151655),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
         "rwkv6-1.6b": (24, 2048, 32, 32, 7168, 65536),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11264, 163840),
     }[arch]
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
            cfg.d_ff, cfg.vocab)
@@ -36,6 +38,12 @@ def test_moe_settings():
     assert (l.n_experts, l.top_k) == (16, 1)
     j = get_config("jamba-1.5-large-398b").moe
     assert (j.n_experts, j.top_k) == (16, 2)
+    m = get_config("moonlight-16b-a3b")
+    assert (m.moe.n_experts, m.moe.top_k, m.moe.d_expert, m.moe.n_shared,
+            m.moe.router, m.moe.routed_scale) == (64, 6, 1408, 2, "sigmoid",
+                                                  2.446)
+    assert m.n_dense_layers == 1 and m.n_periods == 26
+    assert m.mla.q_lora_rank is None and m.moe.held is None
 
 
 def test_jamba_interleave_ratio():
@@ -53,7 +61,7 @@ def test_long_500k_applicability():
 
 def test_grid_cell_count():
     total = sum(len(grid(a)) for a in ARCHS)
-    assert total == 32          # 10*3 + 2 long_500k
+    assert total == 35          # 11*3 + 2 long_500k
 
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))

@@ -3,10 +3,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import (conv_layer, decode_attention, flash_attention,
-                           gemm, kv_write, leakyrelu, maxpool)
+from repro.kernels import (conv_layer, decode_attention, expert_gemm,
+                           flash_attention, gemm, kv_write, leakyrelu,
+                           maxpool)
 from repro.kernels.convlayer.ref import conv_layer_ref
 from repro.kernels.decode_attention.ref import decode_attention_ref
+from repro.kernels.expert_gemm.ref import expert_gemm_ref
 from repro.kernels.flash_attention.ref import (attention_chunked_ref,
                                                attention_ref)
 from repro.kernels.gemm.ref import gemm_ref
@@ -194,3 +196,36 @@ def test_decode_attention_mha_and_softcap(rng):
     ref = decode_attention_ref(q.reshape(B, H, 1, D), k, v, lengths,
                                softcap=25.0).reshape(B, H, D)
     np.testing.assert_allclose(out, ref, atol=2e-3, rtol=1e-3)
+
+
+# ------------------------------------------------------------ expert gemm
+@pytest.mark.parametrize("sizes,bm", [
+    ([5, 0, 17, 0, 3, 32, 0, 1], 16),     # ragged, empty groups between
+    ([0, 0, 0, 9], 8),                    # only the last group has rows
+    ([0, 0, 0], 16),                      # no rows at all
+    ([16, 16], 16),                       # whole tiles
+], ids=["ragged", "last-only", "empty", "whole-tiles"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_expert_gemm_ragged_groups(rng, sizes, bm, dtype):
+    """The grouped kernel (interpret mode) and its oracle give each group's
+    rows times that group's weights, over tiles padded per group and a
+    spare tile past the last."""
+    sizes = np.asarray(sizes, np.int32)
+    g, k, n = len(sizes), 48, 256
+    tiles = -(-sizes // bm)
+    x = np.zeros((int(tiles.sum() + 2) * bm, k), np.float32)
+    starts = np.concatenate([[0], np.cumsum(tiles)[:-1]]) * bm
+    for e, (s0, sz) in enumerate(zip(starts, sizes)):
+        x[s0:s0 + sz] = rng.standard_normal((sz, k))
+    w = rng.standard_normal((g, k, n)).astype(np.float32)
+    xj, wj = jnp.asarray(x, dtype), jnp.asarray(w, dtype)
+    got = expert_gemm(xj, wj, jnp.asarray(sizes), block_m=bm)
+    ref = expert_gemm_ref(xj, wj, jnp.asarray(sizes), block_m=bm)
+    assert got.shape == ref.shape == (x.shape[0], n) and got.dtype == dtype
+    tol = 1e-4 if dtype == jnp.float32 else 0.15
+    xr, wr = np.asarray(xj, np.float32), np.asarray(wj, np.float32)
+    for e, (s0, sz) in enumerate(zip(starts, sizes)):
+        want = xr[s0:s0 + sz] @ wr[e]
+        for out in (got, ref):
+            np.testing.assert_allclose(np.asarray(out, np.float32)[s0:s0 + sz],
+                                       want, atol=tol, rtol=1e-2)

@@ -242,3 +242,65 @@ def test_inplace_decode_matches_xs_ys(arch, over, backend):
         for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(want_cache)):
             np.testing.assert_array_equal(a, b)
         position = position + 1
+
+
+def _decode_matches_forward(cfg, rng, *, B=2, S=16, P=10):
+    """Prefill of P tokens, then decode through the cache to S: every
+    step's logits are the parallel forward's."""
+    model = LM(cfg, ENGINE)
+    params = model.init_params(jax.random.key(1))
+    toks = jnp.array(rng.integers(0, cfg.vocab, (B, S)))
+    full, _ = jax.jit(model.forward)(params, {"tokens": toks})
+    cache = model.init_cache(B, 32, dtype=jnp.float32)
+    lg, cache = jax.jit(model.prefill)(params, {"tokens": toks[:, :P]}, cache)
+    errs = [float(jnp.max(jnp.abs(lg - full[:, P - 1])))]
+    step = jax.jit(model.decode_step)
+    for i in range(P, S - 1):
+        lg, cache = step(params, toks[:, i], jnp.full((B,), i, jnp.int32), cache)
+        errs.append(float(jnp.max(jnp.abs(lg - full[:, i]))))
+    assert max(errs) < 2e-3, errs
+    return model, params, cache
+
+
+def test_direct_q_mla_with_leading_dense_layer(rng):
+    """MLA with a direct q projection (q_lora_rank None) and one leading
+    dense layer ahead of the periods: its own parameter stack and the last
+    cache entry, and prefill + decode agree with forward."""
+    cfg = dataclasses.replace(
+        get_smoke_config("minicpm3-4b"), n_layers=3, n_dense_layers=1,
+        mla=dataclasses.replace(get_smoke_config("minicpm3-4b").mla,
+                                q_lora_rank=None),
+        param_dtype="float32", compute_dtype="float32")
+    model, params, cache = _decode_matches_forward(cfg, rng)
+    assert "q" in params["blocks"][0]["attn"] and "q_down" not in params["blocks"][0]["attn"]
+    assert params["dense_blocks"]["attn"]["q"]["w"].shape[0] == 1
+    assert len(cache) == 2 and cache[-1]["c"].shape[0] == 1
+    assert cache[0]["c"].shape[0] == cfg.n_periods == 2
+
+
+@pytest.mark.parametrize("arch", ["stablelm-3b", "minicpm3-4b"])
+def test_no_leading_dense_layers_no_extra_program(arch):
+    """With no leading dense layers and a q latent, the model keeps one
+    scan per program, one cache entry per pattern position, and no
+    direct q projection."""
+    cfg = get_smoke_config(arch)
+    model = LM(cfg, ENGINE)
+    shapes = model.param_shapes()
+    assert "dense_blocks" not in shapes
+    cache = model.cache_shapes(2, 32)
+    assert len(cache) == len(cfg.pattern) == len(cfg.cache_specs)
+    if cfg.mla is not None:
+        assert "q_down" in shapes["blocks"][0]["attn"]
+    jaxpr = jax.make_jaxpr(model.decode_step)(
+        shapes, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32), cache)
+    assert [e.primitive.name for e in jaxpr.eqns].count("scan") == 1
+
+
+def test_moonlight_param_counts():
+    """The published 16B-A3B: every routed expert, the shared ones, the
+    dense first layer, direct q and untied embeddings counted."""
+    cfg = get_config("moonlight-16b-a3b")
+    assert cfg.param_count() == pytest.approx(15.96e9, rel=0.01)
+    assert cfg.active_param_count() == pytest.approx(2.91e9, rel=0.02)
+    held = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, held=(0, 8)))
+    assert held.param_count() == cfg.param_count()   # the model, not the chip's share

@@ -92,7 +92,7 @@ def test_step_donates_the_previous_cache(rng):
 
 
 @pytest.mark.parametrize("arch,share", [
-    ("stablelm-3b", 1.0), ("minicpm3-4b", 0.0),
+    ("stablelm-3b", 1.0), ("minicpm3-4b", 0.0), ("moonlight-16b-a3b", 0.0),
     ("jamba-1.5-large-398b", None), ("whisper-large-v3", None)])
 def test_decode_inplace_share(arch, share):
     """1 where every leaf is self-attention K/V, 0 where none is, strictly
@@ -218,3 +218,104 @@ def test_decode_span_carries_inplace_share(traced_session):
     _, _, spans = traced_session
     decodes = [s for s in spans if s[0] == "serve.decode"]
     assert decodes and all(s[3]["inplace_share"] == 1.0 for s in decodes)
+
+
+def test_decode_span_has_no_experts_without_moe(traced_session):
+    _, _, spans = traced_session
+    assert all("experts_held" not in s[3] for s in spans
+               if s[0] == "serve.decode")
+
+
+def _moonlight_smoke():
+    import dataclasses
+    cfg = get_smoke_config("moonlight-16b-a3b")
+    return dataclasses.replace(cfg, param_dtype="float32",
+                               compute_dtype="float32")
+
+
+def test_moe_session_matches_manual_greedy(rng):
+    """Moonlight's smoke model (a dense first layer, MoE layers with a
+    held subset, the dropless dispatch): slots decoded together give each
+    request's batch-1 greedy tokens."""
+    cfg = _moonlight_smoke()
+    model = LM(cfg, ArcaneEngine(backend="pallas"))
+    params = model.init_params(jax.random.key(0))
+    prompts = [np.asarray(rng.integers(0, cfg.vocab, int(n)), np.int32)
+               for n in (5, 9, 13)]
+    expected = [manual_greedy(model, params, p, 6) for p in prompts]
+    sess = ServeSession(model, params, max_slots=2, max_len=128)
+    reqs = [sess.submit(p, max_new_tokens=6) for p in prompts]
+    sess.run_to_completion()
+    assert [r.out_tokens for r in reqs] == expected
+
+
+def test_decode_span_carries_experts_held(tmp_path):
+    """An MoE model's ``serve.decode`` names the routed experts it holds."""
+    from jax.profiler import ProfileData
+    model = LM(_moonlight_smoke(), ENGINE)
+    sess = ServeSession(model, model.init_params(jax.random.key(0)),
+                        max_slots=2, max_len=64)
+    sess.submit(np.arange(5, dtype=np.int32), max_new_tokens=3)
+    with jax.profiler.trace(str(tmp_path)):
+        sess.run_to_completion()
+    path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True))
+    decodes = [dict(e.stats) for plane in ProfileData.from_file(path[-1]).planes
+               for line in plane.lines for e in line.events
+               if e.name == "serve.decode"]
+    assert decodes and all(d["experts_held"] == "2-5/8" for d in decodes)
+
+
+def test_moe_session_keeps_expert_rows_per_token():
+    """Each token of an MoE session carries the rows its program's held
+    experts received in each MoE layer: with every expert held, a
+    prefill's layers route prompt x top_k rows and a decode's slots x
+    top_k, and one array serves all the tokens of a decode step."""
+    import dataclasses
+    cfg = _moonlight_smoke()
+    cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, held=(0, cfg.moe.n_experts)))
+    model = LM(cfg, ENGINE)
+    sess = ServeSession(model, model.init_params(jax.random.key(0)),
+                        max_slots=2, max_len=64)
+    reqs = [sess.submit(np.arange(n, dtype=np.int32), max_new_tokens=4)
+            for n in (5, 9)]
+    sess.run_to_completion()
+    n_moe = cfg.n_periods * sum(spec.moe for spec in cfg.pattern)
+    k = cfg.moe.top_k
+    for r in reqs:
+        assert len(r.expert_rows) == len(r.out_tokens) == 4
+        assert all(a.shape == (n_moe, cfg.moe.n_experts) and a.dtype == np.int32
+                   for a in r.expert_rows)
+        np.testing.assert_array_equal(r.expert_rows[0].sum(1), len(r.prompt) * k)
+        for a in r.expert_rows[1:]:
+            np.testing.assert_array_equal(a.sum(1), 2 * k)
+    assert reqs[0].expert_rows[1] is reqs[1].expert_rows[1]
+
+
+def test_dense_model_has_no_expert_rows(rng):
+    """Without MoE layers, asking for expert rows traces the same programs,
+    the serving steps return (logits, cache) alone, and the session's
+    requests keep no rows."""
+    from repro.train.step import make_serve_steps
+    model = LM(get_smoke_config("stablelm-3b"), ENGINE)
+    params = model.param_shapes()
+    cache = model.cache_shapes(2, 32)
+    tokens = jax.ShapeDtypeStruct((2,), jnp.int32)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 7), jnp.int32)}
+    one = model.cache_shapes(1, 32)
+    rows = []
+    for fn, args in ((model.decode_step, (params, tokens, tokens, cache)),
+                     (model.prefill, (params, batch, one))):
+        plain = str(jax.make_jaxpr(fn)(*args))
+        asked = str(jax.make_jaxpr(lambda *a: fn(*a, expert_rows=rows))(*args))
+        assert plain == asked and rows == []
+    prefill_step, decode_step = make_serve_steps(model, expert_rows=True)
+    assert len(jax.eval_shape(decode_step, params, tokens, tokens, cache)) == 2
+    assert len(jax.eval_shape(prefill_step, params, batch, one)) == 2
+    sess = ServeSession(model, model.init_params(jax.random.key(0)),
+                        max_slots=2, max_len=32)
+    req = sess.submit(np.asarray(rng.integers(0, 64, 5), np.int32),
+                      max_new_tokens=3)
+    sess.run_to_completion()
+    assert len(req.out_tokens) == 3 and req.expert_rows == []

@@ -10,7 +10,8 @@ from repro.configs import get_smoke_config
 from repro.configs.base import LayerSpec, MambaConfig, ModelConfig, MoEConfig
 from repro.core.engine import ArcaneEngine
 from repro.models.mamba import mamba_forward, mamba_init
-from repro.models.moe import moe, moe_init
+from repro.models.mlp import mlp
+from repro.models.moe import moe, moe_init, route
 from repro.models.rwkv6 import rwkv_init, rwkv_time_mix
 
 ENGINE = ArcaneEngine(backend="ref")
@@ -87,13 +88,133 @@ def test_rwkv_chunk_invariance(rng):
 
 
 # ------------------------------------------------------------------- MoE
-def _moe_cfg(cap=8.0, e=4, k=2):
+def _moe_cfg(cap=8.0, e=4, k=2, **moe_kw):
     return ModelConfig(
         name="moe", family="moe", n_layers=1, d_model=32, n_heads=4,
         n_kv_heads=4, d_ff=48, vocab=64,
         pattern=(LayerSpec(kind="attn", moe=True),),
-        moe=MoEConfig(n_experts=e, top_k=k, capacity_factor=cap),
+        moe=MoEConfig(n_experts=e, top_k=k, capacity_factor=cap, **moe_kw),
         param_dtype="float32", compute_dtype="float32")
+
+
+def _dense_loop(p, cfg, t):
+    """Every held expert over every token, weighted by its gate where the
+    token chose it; the router written out again; the shared experts once."""
+    m = cfg.moe
+    logits = np.asarray(t, np.float64) @ np.asarray(p["router"]["w"], np.float64)
+    if m.router == "sigmoid":
+        s = 1.0 / (1.0 + np.exp(-logits))
+        ids = np.argsort(-(s + np.asarray(p["router"]["bias"])), -1)[:, :m.top_k]
+    else:
+        s = np.exp(logits - logits.max(-1, keepdims=True))
+        s /= s.sum(-1, keepdims=True)
+        ids = np.argsort(-s, -1)[:, :m.top_k]
+    w = np.take_along_axis(s, ids, -1)
+    w = w / w.sum(-1, keepdims=True) * m.routed_scale
+    lo, hi = m.held_range
+    ref = np.zeros(t.shape, np.float64)
+    for e in range(lo, hi):
+        ye = np.asarray(jax.nn.silu(t @ p["gate"][e - lo]) * (t @ p["up"][e - lo])
+                        @ p["down"][e - lo], np.float64)
+        ref += (w * (ids == e)).sum(-1)[:, None] * ye
+    if "shared" in p:
+        f = p["shared"]
+        ref += np.asarray(jax.nn.silu(t @ f["gate"]["w"]) * (t @ f["up"]["w"])
+                          @ f["down"]["w"], np.float64)
+    return ref
+
+
+def _with_bias(p, cfg):
+    """Router bias of the scores' own size, so that it steers the choice."""
+    if cfg.moe.router == "sigmoid":
+        p["router"]["bias"] = 0.1 * jax.random.normal(
+            jax.random.key(9), p["router"]["bias"].shape)
+    return p
+
+
+MOE_CASES = {
+    "softmax": dict(),
+    "sigmoid-bias": dict(router="sigmoid", routed_scale=2.5),
+    "shared": dict(router="sigmoid", routed_scale=2.5, n_shared=2,
+                   d_expert=24),
+    "held": dict(router="sigmoid", routed_scale=2.5, n_shared=1,
+                 d_expert=24, held=(3, 7)),
+    "softmax-held": dict(n_shared=1, held=(0, 5)),
+}
+
+
+@pytest.mark.parametrize("engine", ["ref", "pallas"])
+@pytest.mark.parametrize("case", sorted(MOE_CASES))
+def test_moe_layer_matches_dense_loop(rng, case, engine):
+    """Dropless dispatch (expert_gemm on either backend, interpret mode
+    for pallas) and capacity dispatch with room for every token both give
+    the dense loop over the held experts, for each router, with shared
+    experts and a held subset."""
+    cfg = _moe_cfg(cap=16.0, e=8, k=3, **MOE_CASES[case])
+    p = _with_bias(moe_init(jax.random.key(0), cfg), cfg)
+    x = jnp.asarray(rng.standard_normal((2, 12, 32)), jnp.float32)
+    ref = _dense_loop(p, cfg, np.asarray(x).reshape(-1, 32))
+    outs = [moe(ArcaneEngine(backend=engine), p, cfg, x, dropless=True)[0]]
+    if engine == "ref":
+        outs.append(moe(ENGINE, p, cfg, x)[0])
+    for out in outs:
+        np.testing.assert_allclose(np.asarray(out).reshape(-1, 32), ref,
+                                   atol=2e-4, rtol=2e-3)
+
+
+@pytest.mark.parametrize("case", ["softmax", "held", "softmax-held"])
+def test_moe_dropless_counts_rows_per_held_expert(rng, case):
+    """Serving's aux output: the rows each held expert received, the
+    (token, expert) pairs the router sent it; the capacity dispatch's aux
+    stays the balance loss, a float32 scalar."""
+    cfg = _moe_cfg(cap=16.0, e=8, k=3, **MOE_CASES[case])
+    p = _with_bias(moe_init(jax.random.key(0), cfg), cfg)
+    x = jnp.asarray(rng.standard_normal((2, 12, 32)), jnp.float32)
+    _, rows = moe(ENGINE, p, cfg, x, dropless=True)
+    _, ids, _ = route(p, cfg, x.reshape(-1, 32))
+    lo, hi = cfg.moe.held_range
+    want = np.bincount(np.asarray(ids).ravel(), minlength=8)[lo:hi]
+    assert rows.dtype == jnp.int32 and rows.shape == (hi - lo,)
+    np.testing.assert_array_equal(np.asarray(rows), want)
+    _, aux = moe(ENGINE, p, cfg, x)
+    assert aux.dtype == jnp.float32 and aux.shape == ()
+
+
+def test_moe_sigmoid_bias_steers_choice_not_gates(rng):
+    """noaux_tc: the bias moves which experts are chosen; the gates are
+    the unbiased scores of the chosen, renormalised and scaled."""
+    cfg = _moe_cfg(e=8, k=2, router="sigmoid", routed_scale=2.0)
+    p = moe_init(jax.random.key(0), cfg)
+    t = jnp.asarray(rng.standard_normal((16, 32)), jnp.float32)
+    _, ids0, g0 = route(p, cfg, t)
+    p["router"]["bias"] = p["router"]["bias"].at[5].set(10.0)
+    _, ids1, g1 = route(p, cfg, t)
+    assert (np.asarray(ids1) == 5).any(-1).all()
+    assert not (np.asarray(ids0) == 5).any(-1).all()
+    s = jax.nn.sigmoid(t @ p["router"]["w"])
+    want = np.take_along_axis(np.asarray(s), np.asarray(ids1), -1)
+    np.testing.assert_allclose(np.asarray(g1), 2.0 * want / want.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(g0).sum(-1), 2.0, rtol=1e-5)
+
+
+@pytest.mark.parametrize("dropless", [True, False], ids=["dropless", "capacity"])
+def test_moe_held_shares_add_up_to_the_uncut_layer(rng, dropless):
+    """Expert parallelism on one chip's share: the outputs of 8 disjoint
+    held shares of 16 experts, the shared experts counted once, add up to
+    the layer that holds every expert."""
+    over = dict(router="sigmoid", routed_scale=2.446, n_shared=2, d_expert=24)
+    full = _moe_cfg(cap=32.0, e=16, k=4, **over)
+    p = _with_bias(moe_init(jax.random.key(1), full), full)
+    x = jnp.asarray(rng.standard_normal((2, 10, 32)), jnp.float32)
+    whole, _ = moe(ENGINE, p, full, x, dropless=dropless)
+    shared = np.asarray(mlp(ENGINE, p["shared"], full, x))
+    total = -7 * shared
+    for i in range(8):
+        cut = _moe_cfg(cap=32.0, e=16, k=4, held=(2 * i, 2 * i + 2), **over)
+        part = {**p, **{w: p[w][2 * i: 2 * i + 2] for w in ("gate", "up", "down")}}
+        total = total + np.asarray(moe(ENGINE, part, cut, x, dropless=dropless)[0])
+    np.testing.assert_allclose(total, np.asarray(whole), atol=2e-5, rtol=1e-4)
 
 
 def test_moe_matches_dense_reference_at_high_capacity(rng):

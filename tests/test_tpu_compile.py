@@ -4,7 +4,9 @@ Nothing here runs on a chip. Each test lowers a program for one chip of a
 ``v5e:2x2`` topology that the TPU compiler installed with JAX describes, and
 asserts the program holds a Mosaic kernel (``tpu_custom_call``): what the
 chip's compiler refuses fails here, at no chip time. Shapes are stablelm-3b's
-published widths (d_model 2560, 32 heads of 80, d_ff 6912, vocab 50304, bf16).
+published widths (d_model 2560, 32 heads of 80, d_ff 6912, vocab 50304, bf16),
+and Moonlight-16B-A3B's for the routed experts (d_model 2048, experts of
+1408, 8 held).
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and the test workers
@@ -19,8 +21,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core.engine import ArcaneEngine
-from repro.kernels import decode_attention, flash_attention, gemm
+from repro.kernels import decode_attention, expert_gemm, flash_attention, gemm
 from repro.models.transformer import LM
+from repro.train.step import make_serve_steps
 
 D, HEADS, HEAD_DIM, D_FF, VOCAB = 2560, 32, 80, 6912, 50304
 MAX_LEN = 2048
@@ -114,3 +117,38 @@ def test_decode_step_compiles_at_unaligned_max_len(one_chip):
     kernel still move 128-lane blocks (the last one runs past the cache),
     never a whole layer, so the step fits the chip's scoped VMEM."""
     assert "tpu_custom_call" in _decode_step_text(one_chip, 1000)
+
+
+@pytest.mark.parametrize("rows,k,n,block_m", [
+    (512, 2048, 1408, 16),     # decode at 64 slots: gate / up
+    (512, 1408, 2048, 16),     # decode: down
+    (3456, 2048, 1408, 48),    # prefill of 512 tokens
+], ids=["decode_up", "decode_down", "prefill_up"])
+def test_expert_gemm_compiles(one_chip, rows, k, n, block_m):
+    text = _compiled_text(
+        lambda x, w, s: expert_gemm(x, w, s, block_m=block_m, interpret=False),
+        _spec(one_chip, (rows, k)), _spec(one_chip, (8, k, n)),
+        _spec(one_chip, (8,), jnp.int32))
+    assert "tpu_custom_call" in text and "%expert_gemm" in text
+
+
+def test_moonlight_decode_step_compiles(one_chip):
+    """The dense first layer and one MoE layer of Moonlight-16B-A3B at its
+    published widths, 8 experts held, at 64 slots of 1024, as the session
+    runs it (with the rows per held expert): the grouped expert kernel
+    keeps its name beside the GEMM and decode kernels."""
+    full = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(full, n_layers=2, moe=dataclasses.replace(
+        full.moe, held=(0, 8)))
+    model = LM(cfg, ArcaneEngine(backend="pallas", interpret=False))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+
+    text = _compiled_text(
+        make_serve_steps(model, expert_rows=True)[1],
+        on_chip(model.param_shapes()),
+        _spec(one_chip, (64,), jnp.int32), _spec(one_chip, (64,), jnp.int32),
+        on_chip(model.cache_shapes(64, 1024)))
+    for kernel in ("%expert_gemm", "%gemm", "%decode_attention"):
+        assert kernel in text
