@@ -8,6 +8,11 @@ the decode kernel skips cache pages past each slot's length, so a just-
 admitted short sequence does not pay for its neighbours (the kernel-level
 straggler mitigation described in the decode kernel docstring).
 
+Tokens are selected on the device: the jitted prefill and decode programs
+end in ``select_tokens`` (argmax where a slot's temperature is 0, else a
+categorical draw), so the host fetches (B,) int32 token ids and never the
+(B, V) logits.
+
 Every phase of ``step()`` runs inside a ``jax.profiler.TraceAnnotation``
 span, so a profiler trace shows what the host was doing while the device
 waited. The spans cost under a microsecond each with the profiler
@@ -18,16 +23,18 @@ off, and change nothing that is jitted::
     │  ├─ serve.init_cache           the batch-1 cache
     │  ├─ serve.prefill              dispatch of the jitted prefill
     │  ├─ serve.insert               the copy into the batched cache
-    │  └─ serve.first_token          sampling the first token
+    │  └─ serve.first_token          wait for the copy; fetch the token
     ├─ serve.decode  (inplace_share[, experts_held])
     │                                dispatch of the jitted decode step
-    ├─ serve.fetch                   logits to the host
-    └─ serve.sample                  per-slot sampling and bookkeeping
+    ├─ serve.fetch  (bytes)          token ids (and expert rows) to host
+    └─ serve.sample                  per-slot bookkeeping: no device work
 
 The admission spans carry the request's ``uid``. ``serve.decode`` carries
 ``inplace_share``: the share of the cache's bytes that the decode step
 updates in place (``decode_inplace_share``), and for an MoE model
 ``experts_held``, the routed experts this device computes (``"0-7/64"``).
+``serve.fetch`` carries ``bytes``, what crossed to the host that step: 4
+a slot, plus the expert rows of an MoE model.
 
 An MoE model's prefill and decode programs also return the rows each held
 expert received in each MoE layer; every request keeps, per output token,
@@ -85,6 +92,24 @@ def _insert_slot(batched: PyTree, one: PyTree, slot: int) -> PyTree:
     return jax.tree.map(put, batched, one)
 
 
+def select_tokens(logits: jax.Array, temperature: jax.Array,
+                  key: jax.Array) -> jax.Array:
+    """One token per row of ``logits`` (B, V): the argmax where the row's
+    ``temperature`` (B,) is 0 or below (the first index wins a tie), else a
+    draw from ``softmax(logits / temperature)``. A batch with no positive
+    temperature draws no random bits. → (B,) int32."""
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, -1).astype(jnp.int32)
+    hot = temperature > 0
+
+    def draw(_):
+        t = jnp.where(hot, temperature, 1.0)[:, None]
+        drawn = jax.random.categorical(key, logits / t, -1).astype(jnp.int32)
+        return jnp.where(hot, drawn, greedy)
+
+    return jax.lax.cond(jnp.any(hot), draw, lambda _: greedy, None)
+
+
 def decode_inplace_share(model: LM, cache_shapes: tuple) -> float:
     """Share of the cache's bytes that ``LM.decode_step`` updates in place,
     from the shapes of a cache (``LM.cache_shapes``); the leaves that take
@@ -112,8 +137,10 @@ class ServeSession:
         self.positions = np.zeros((max_slots,), np.int32)
         self.slots: list[Optional[Request]] = [None] * max_slots
         self.last_tokens = np.zeros((max_slots,), np.int32)
+        self.temperatures = np.zeros((max_slots,), np.float32)
         self._uid = 0
         self._key = jax.random.key(seed)
+        self._draws = 0              # programs run: each folds it into the key
         self.decode_inplace_share = decode_inplace_share(
             model, model.cache_shapes(max_slots, max_len))
         self._decode_span = {"inplace_share": self.decode_inplace_share}
@@ -121,11 +148,25 @@ class ServeSession:
             lo, hi = model.cfg.moe.held_range
             self._decode_span["experts_held"] = (
                 f"{lo}-{hi - 1}/{model.cfg.moe.n_experts}")
-        prefill_step, decode_step = make_serve_steps(
+        model_prefill, model_decode = make_serve_steps(
             model, expert_rows=any(spec.moe for spec in model.cfg.pattern))
 
-        def prefill(params, batch, cache):       # traced as jit_prefill
-            return prefill_step(params, batch, cache)
+        # Each program picks its tokens after the model's step returns, so
+        # whatever wraps ``LM.decode_step`` reaches the served tokens.
+        def prefill(params, batch, cache, temperature, key, draw):
+            logits, cache, *rows = model_prefill(params, batch, cache)
+            tok = select_tokens(logits, temperature,
+                                jax.random.fold_in(key, draw))
+            return (tok[0], cache, *rows)            # traced as jit_prefill
+
+        def decode_step(params, tokens, positions, cache, temperature, key,
+                        draw):                     # traced as jit_decode_step
+            logits, cache, *rows = model_decode(params, tokens, positions,
+                                                cache)
+            tok = select_tokens(logits, temperature,
+                                jax.random.fold_in(key, draw))
+            return (tok, cache, *rows)
+
         self._prefill1 = jax.jit(prefill)
         self._decode = jax.jit(decode_step, donate_argnums=(3,))
         self.pending: list[Request] = []
@@ -155,13 +196,19 @@ class ServeSession:
                 with TraceAnnotation("serve.init_cache", uid=req.uid):
                     one_cache = self.model.init_cache(1, self.max_len)
                 with TraceAnnotation("serve.prefill", uid=req.uid):
-                    logits, one_cache, *rows = self._prefill1(
+                    tok, one_cache, *rows = self._prefill1(
                         self.params, {"tokens": jnp.asarray(req.prompt[None])},
-                        one_cache)
+                        one_cache,
+                        np.full((1,), req.temperature, np.float32),
+                        self._key, self._next_draw())
                 with TraceAnnotation("serve.insert", uid=req.uid):
                     self.cache = _insert_slot(self.cache, one_cache, slot)
                 with TraceAnnotation("serve.first_token", uid=req.uid):
-                    tok = int(self._sample(logits, req.temperature)[0])
+                    # The cache the copy replaces is freed only once the
+                    # copy has run; wait for it, or the next admission's or
+                    # decode's buffers are laid out beside both caches.
+                    jax.block_until_ready(self.cache)
+                    tok = int(tok)
                 req.t_first = time.perf_counter()
                 req.out_tokens.append(tok)
                 if rows:
@@ -169,13 +216,11 @@ class ServeSession:
                 self.slots[slot] = req
                 self.positions[slot] = s
                 self.last_tokens[slot] = tok
+                self.temperatures[slot] = req.temperature
 
-    def _sample(self, logits: jax.Array, temperature: float) -> np.ndarray:
-        if temperature <= 0.0:
-            return np.asarray(jnp.argmax(logits, -1), np.int32)
-        self._key, sub = jax.random.split(self._key)
-        return np.asarray(
-            jax.random.categorical(sub, logits / temperature, -1), np.int32)
+    def _next_draw(self) -> np.uint32:
+        self._draws += 1
+        return np.uint32(self._draws & 0xFFFFFFFF)
 
     def step(self) -> int:
         """Admit pending requests, decode one token for all live slots.
@@ -188,29 +233,31 @@ class ServeSession:
             with TraceAnnotation("serve.decode", **self._decode_span):
                 tokens = jnp.asarray(self.last_tokens)
                 positions = jnp.asarray(self.positions)
-                logits, self.cache, *rows = self._decode(
-                    self.params, tokens, positions, self.cache)
-            with TraceAnnotation("serve.fetch"):
-                lg = np.asarray(logits, np.float32)
+                temperatures = jnp.asarray(self.temperatures)
+                toks, self.cache, *rows = self._decode(
+                    self.params, tokens, positions, self.cache, temperatures,
+                    self._key, self._next_draw())
+            nbytes = toks.nbytes + sum(r.nbytes for r in rows)
+            with TraceAnnotation("serve.fetch", bytes=nbytes):
+                toks = np.asarray(toks)
                 rows = np.asarray(rows[0]) if rows else None
             with TraceAnnotation("serve.sample"):
                 for slot in live:
                     req = self.slots[slot]
-                    tok = self._sample(jnp.asarray(lg[slot : slot + 1]),
-                                       req.temperature)[0]
-                    req.out_tokens.append(int(tok))
+                    tok = int(toks[slot])
+                    req.out_tokens.append(tok)
                     if rows is not None:
                         req.expert_rows.append(rows)
                     self.positions[slot] += 1
-                    self.last_tokens[slot] = int(tok)
-                    hit_eos = (self.eos_id is not None
-                               and int(tok) == self.eos_id)
+                    self.last_tokens[slot] = tok
+                    hit_eos = self.eos_id is not None and tok == self.eos_id
                     full = len(req.out_tokens) >= req.max_new_tokens or \
                         self.positions[slot] + 1 >= self.max_len
                     if hit_eos or full:
                         req.done = True
                         self.finished.append(req)
                         self.slots[slot] = None
+                        self.temperatures[slot] = 0.0   # free: draws nothing
             return len(live)
 
     def run_to_completion(self, max_steps: int = 10_000) -> list[Request]:

@@ -10,7 +10,8 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.core.engine import ArcaneEngine
 from repro.models.transformer import LM
-from repro.serving.engine import ServeSession, decode_inplace_share
+from repro.serving.engine import (ServeSession, decode_inplace_share,
+                                  select_tokens)
 
 ENGINE = ArcaneEngine(backend="ref")
 
@@ -110,6 +111,19 @@ def test_decode_inplace_share(arch, share):
         assert got == share
 
 
+def _decode_args(sess):
+    """Shapes of the served decode program's tokens and positions."""
+    ints = jax.ShapeDtypeStruct((sess.max_slots,), jnp.int32)
+    return ints, ints
+
+
+def _select_args(sess):
+    """Shapes of its per-slot temperatures, key and draw counter."""
+    return (jax.ShapeDtypeStruct((sess.max_slots,), jnp.float32),
+            jax.eval_shape(lambda: sess._key),
+            jax.ShapeDtypeStruct((), jnp.uint32))
+
+
 def test_decode_step_writes_no_whole_layer():
     """The served decode step, lowered on the kernel path, neither slices
     nor updates a whole layer's K or V: only single positions go in, and
@@ -122,9 +136,8 @@ def test_decode_step_writes_no_whole_layer():
     layer = "x".join(map(str, k.shape[1:]))
     dtype = {"bfloat16": "bf16", "float32": "f32"}[str(k.dtype)]
     whole = {f"tensor<{layer}x{dtype}>", f"tensor<1x{layer}x{dtype}>"}
-    ints = jax.ShapeDtypeStruct((2,), jnp.int32)
-    text = sess._decode.lower(model.param_shapes(), ints, ints,
-                              sess.cache).as_text()
+    text = sess._decode.lower(model.param_shapes(), *_decode_args(sess),
+                              sess.cache, *_select_args(sess)).as_text()
     ops = [ln for ln in text.splitlines()
            if re.search(r"stablehlo\.dynamic_(update_)?slice", ln)]
     assert ops, "no dynamic slices at all: the parse is wrong"
@@ -249,20 +262,24 @@ def test_moe_session_matches_manual_greedy(rng):
     assert [r.out_tokens for r in reqs] == expected
 
 
-def test_decode_span_carries_experts_held(tmp_path):
-    """An MoE model's ``serve.decode`` names the routed experts it holds."""
+def _profiled_spans(tmp_path, sess, name):
+    """Args of every ``name`` span while ``sess`` runs to completion."""
     from jax.profiler import ProfileData
-    model = LM(_moonlight_smoke(), ENGINE)
-    sess = ServeSession(model, model.init_params(jax.random.key(0)),
-                        max_slots=2, max_len=64)
-    sess.submit(np.arange(5, dtype=np.int32), max_new_tokens=3)
     with jax.profiler.trace(str(tmp_path)):
         sess.run_to_completion()
     path = sorted(glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
                             recursive=True))
-    decodes = [dict(e.stats) for plane in ProfileData.from_file(path[-1]).planes
-               for line in plane.lines for e in line.events
-               if e.name == "serve.decode"]
+    return [dict(e.stats) for plane in ProfileData.from_file(path[-1]).planes
+            for line in plane.lines for e in line.events if e.name == name]
+
+
+def test_decode_span_carries_experts_held(tmp_path):
+    """An MoE model's ``serve.decode`` names the routed experts it holds."""
+    model = LM(_moonlight_smoke(), ENGINE)
+    sess = ServeSession(model, model.init_params(jax.random.key(0)),
+                        max_slots=2, max_len=64)
+    sess.submit(np.arange(5, dtype=np.int32), max_new_tokens=3)
+    decodes = _profiled_spans(tmp_path, sess, "serve.decode")
     assert decodes and all(d["experts_held"] == "2-5/8" for d in decodes)
 
 
@@ -319,3 +336,100 @@ def test_dense_model_has_no_expert_rows(rng):
                       max_new_tokens=3)
     sess.run_to_completion()
     assert len(req.out_tokens) == 3 and req.expert_rows == []
+
+
+# ------------------------------------------------- selection on the device
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_select_tokens_greedy_is_host_argmax(dtype):
+    """At temperature 0 the device picks what a row-wise argmax of the
+    logits cast to float32 picks on the host, the first index of a tie
+    included."""
+    rng = np.random.default_rng(3)
+    logits = rng.standard_normal((6, 50)).astype(np.float32)
+    logits[0, [7, 30]] = logits[0].max() + 1.0        # tie: 7 wins
+    logits[1, [0, 49]] = logits[1].max() + 1.0        # tie: 0 wins
+    logits[2, :] = 0.0                                # all tied: 0 wins
+    logits[3, [48, 49]] = logits[3].max() + 1.0       # tie: 48 wins
+    lg = jnp.asarray(logits, dtype)
+    got = select_tokens(lg, jnp.zeros((6,), jnp.float32), jax.random.key(0))
+    want = np.argmax(np.asarray(lg, np.float32), -1)
+    assert got.dtype == jnp.int32 and got.shape == (6,)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert list(np.asarray(got)[:4]) == [7, 0, 0, 48]
+
+
+def test_select_tokens_draws_at_temperature():
+    """At temperature 1, logits [0, log 3] pick token 1 three times in
+    four."""
+    n = 4000
+    logits = jnp.tile(jnp.asarray([0.0, np.log(3.0)], jnp.float32), (n, 1))
+    got = np.asarray(select_tokens(logits, jnp.ones((n,), jnp.float32),
+                                   jax.random.key(11)))
+    assert set(np.unique(got)) <= {0, 1}
+    assert abs(got.mean() - 0.75) <= 0.03
+
+
+@pytest.mark.parametrize("arch", ["stablelm-3b", "moonlight-16b-a3b"])
+def test_decode_program_returns_token_ids(arch):
+    """The session's decode program returns (B,) int32 token ids where the
+    model's step returns (B, V) logits, and its prefill one int32."""
+    cfg = get_smoke_config(arch)
+    model = LM(cfg, ENGINE)
+    sess = ServeSession(model, None, max_slots=3, max_len=32)
+    params = model.param_shapes()
+    toks, _, *rows = jax.eval_shape(
+        sess._decode, params, *_decode_args(sess),
+        model.cache_shapes(3, 32), *_select_args(sess))
+    assert (toks.shape, toks.dtype) == ((3,), jnp.int32)
+    assert len(rows) == (cfg.moe is not None)
+    one = jax.ShapeDtypeStruct((1,), jnp.float32)
+    tok, *_ = jax.eval_shape(
+        sess._prefill1, params,
+        {"tokens": jax.ShapeDtypeStruct((1, 7), jnp.int32)},
+        model.cache_shapes(1, 32), one, *_select_args(sess)[1:])
+    assert (tok.shape, tok.dtype) == ((), jnp.int32)
+
+
+def test_mixed_temperatures_keep_greedy_slot(rng):
+    """A slot at temperature 0 beside one at 1.0 decodes what it decodes
+    alone, and the mix compiles no second decode or prefill program."""
+    cfg = get_smoke_config("stablelm-3b")
+    model = LM(cfg, ENGINE)
+    params = model.init_params(jax.random.key(0))
+    p = np.asarray(rng.integers(0, cfg.vocab, 6), np.int32)
+    other = np.asarray(rng.integers(0, cfg.vocab, 6), np.int32)
+
+    alone = ServeSession(model, params, max_slots=2, max_len=64)
+    want = alone.submit(p, max_new_tokens=8)
+    alone.run_to_completion()
+
+    sess = ServeSession(model, params, max_slots=2, max_len=64, seed=5)
+    got = sess.submit(p, max_new_tokens=8)
+    hot = sess.submit(other, max_new_tokens=8, temperature=1.0)
+    sess.run_to_completion()
+    assert got.out_tokens == want.out_tokens
+    assert len(hot.out_tokens) == 8
+    assert all(0 <= t < cfg.vocab for t in hot.out_tokens)
+    assert sess._decode._cache_size() == 1
+    assert sess._prefill1._cache_size() == 1
+
+
+def test_fetch_span_carries_token_bytes(traced_session):
+    """A dense model's step fetches 4 bytes a slot: the token ids."""
+    _, _, spans = traced_session
+    fetches = [s for s in spans if s[0] == "serve.fetch"]
+    assert fetches and all(s[3]["bytes"] == 2 * 4 for s in fetches)
+
+
+def test_moe_fetch_span_carries_token_and_row_bytes(tmp_path):
+    """An MoE model's step fetches its token ids and the expert rows."""
+    cfg = _moonlight_smoke()
+    model = LM(cfg, ENGINE)
+    sess = ServeSession(model, model.init_params(jax.random.key(0)),
+                        max_slots=2, max_len=64)
+    sess.submit(np.arange(5, dtype=np.int32), max_new_tokens=3)
+    fetches = _profiled_spans(tmp_path, sess, "serve.fetch")
+    n_moe = cfg.n_periods * sum(spec.moe for spec in cfg.pattern)
+    lo, hi = cfg.moe.held_range
+    rows = n_moe * (hi - lo) * 4
+    assert fetches and all(d["bytes"] == 2 * 4 + rows for d in fetches)

@@ -23,6 +23,7 @@ from repro.configs import get_config
 from repro.core.engine import ArcaneEngine
 from repro.kernels import decode_attention, expert_gemm, flash_attention, gemm
 from repro.models.transformer import LM
+from repro.serving.engine import select_tokens
 from repro.train.step import make_serve_steps
 
 D, HEADS, HEAD_DIM, D_FF, VOCAB = 2560, 32, 80, 6912, 50304
@@ -152,3 +153,19 @@ def test_moonlight_decode_step_compiles(one_chip):
         on_chip(model.cache_shapes(64, 1024)))
     for kernel in ("%expert_gemm", "%gemm", "%decode_attention"):
         assert kernel in text
+
+
+@pytest.mark.parametrize("batch,vocab", [(64, 163840), (12, VOCAB)],
+                         ids=["moonlight_64", "stablelm_12"])
+def test_select_tokens_compiles(one_chip, batch, vocab):
+    """The serving programs' token selection at a cell's slots and
+    vocabulary: the random draw sits behind a conditional, and neither
+    branch keeps a (B, V) array of its own."""
+    compiled = jax.jit(
+        lambda lg, t, key, n: select_tokens(lg, t, jax.random.fold_in(key, n))
+    ).lower(_spec(one_chip, (batch, vocab), jnp.float32),
+            _spec(one_chip, (batch,), jnp.float32),
+            jax.eval_shape(lambda: jax.random.key(0)),
+            _spec(one_chip, (), jnp.uint32)).compile()
+    assert "conditional" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < batch * vocab * 4
